@@ -879,6 +879,9 @@ def run_live_smoke(out: str = "BENCH_churn.json") -> dict:
     asserted on (timing is machine-dependent); the *functional* bits —
     zero leaked replicas, every completed invoke carrying real tokens —
     are asserted."""
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     cell = live_smoke_point()
     _print_live_smoke(cell)
     real = live_grid_point(4, 20.0, 1.0, n_functions=2)
@@ -921,29 +924,18 @@ def _live_spec(mode: str = "process", max_slots: int = 4,
                             max_slots=max_slots, default_max_new=max_new)
 
 
-def live_cold_warm_point(mode: str = "process", n_warm: int = 4) -> dict:
-    """Cold vs warm live sandbox creation — the shared-executable-cache
-    headline (acceptance: warm >= 10x faster than cold).
-
-    Process mode: a fresh ``ExecutableCache``; the first creation compiles
-    (cold), the rest hit the cache (warm). Container mode: a fresh
-    persistent-compile-cache dir; the first spawned worker compiles and
-    populates it, the next deserializes instead (n_warm is clamped to 1 —
-    workers cost seconds each)."""
-    import shutil
-    import tempfile
-
+def live_cold_warm_point(n_warm: int = 4) -> dict:
+    """Cold vs warm process-mode sandbox creation — the shared-executable-
+    cache headline (acceptance: warm >= 10x faster than cold). A fresh
+    ``ExecutableCache``: the first creation builds the decode executable
+    (compile, or a load from the persistent compile cache), the rest reuse
+    it."""
     from repro.core.abstractions import Sandbox
     from repro.live import LiveBackend
     from repro.serving.exec_cache import ExecutableCache
 
-    cache_dir = tempfile.mkdtemp(prefix="live-xla-cache-") \
-        if mode == "container" else None
-    if mode == "container":
-        n_warm = 1
-    lb = LiveBackend(default_spec=_live_spec(mode),
-                     exec_cache=ExecutableCache(),
-                     compile_cache_dir=cache_dir)
+    lb = LiveBackend(default_spec=_live_spec("process"),
+                     exec_cache=ExecutableCache())
     try:
         for i in range(1 + n_warm):
             sb = Sandbox(sandbox_id=i + 1, function_name="lf",
@@ -954,15 +946,35 @@ def live_cold_warm_point(mode: str = "process", n_warm: int = 4) -> dict:
             "cold/warm split did not land where expected"
         cold = rows[0]["wall_s"]
         warm = float(np.median([r["wall_s"] for r in rows[1:]]))
-        return {"mode": mode, "cold_create_s": round(cold, 4),
+        return {"mode": "process", "cold_create_s": round(cold, 4),
                 "warm_create_s": round(warm, 4),
                 "warm_speedup": round(cold / max(warm, 1e-9), 1),
                 "n_warm": n_warm,
                 "exec_cache": lb.cache_stats()}
     finally:
         lb.close()
-        if cache_dir:
-            shutil.rmtree(cache_dir, ignore_errors=True)
+
+
+def live_container_cold_warm_point() -> dict:
+    """Cold vs warm container-mode creation. The first worker runs with the
+    persistent compile cache off, so it compiles (cold). The second fills
+    the cache, or finds it filled by an earlier run; the third loads its
+    executables from it (warm)."""
+    from repro.live.container import ContainerSandbox
+
+    spec = _live_spec("container")
+    rows = []
+    for persistent in (False, True, True):
+        t0 = time.perf_counter()
+        sb = ContainerSandbox(spec, persistent_cache=persistent)
+        rows.append((sb.cold, time.perf_counter() - t0))
+        sb.close()
+    assert rows[0][0] and not rows[-1][0], \
+        "cold/warm split did not land where expected"
+    cold, warm = rows[0][1], rows[-1][1]
+    return {"mode": "container", "cold_create_s": round(cold, 4),
+            "warm_create_s": round(warm, 4),
+            "warm_speedup": round(cold / max(warm, 1e-9), 1), "n_warm": 1}
 
 
 def live_grid_point(n_workers: int, rate: float, duration: float,
@@ -1098,12 +1110,25 @@ def live_grid_section(smoke: bool = False) -> dict:
     Azure-trace slice. Per-phase wall measurements are folded into a
     calibrated ``DirigentCosts`` candidate (``costs_candidate``) for DES
     cross-checking."""
+    import jax
+
+    from repro.compile_cache import enable_compile_cache
     from repro.core.costmodel import live_calibrated_candidate
 
+    enable_compile_cache()
     section: dict = {"provenance": bench_provenance()}
-    section["cold_warm"] = [live_cold_warm_point("process")]
-    if not smoke:
-        section["cold_warm"].append(live_cold_warm_point("container"))
+    section["cold_warm"] = [live_cold_warm_point()]
+    if smoke:
+        pass
+    elif jax.default_backend() == "tpu":
+        # one process per chip: this process holds it, so a spawned
+        # container worker could not run (ROADMAP reach item 6)
+        section["container_cells"] = (
+            "left out: container mode refuses to start on a TPU backend")
+        print(f"live container cells {section['container_cells']}",
+              flush=True)
+    else:
+        section["cold_warm"].append(live_container_cold_warm_point())
     for row in section["cold_warm"]:
         print(f"live cold/warm mode={row['mode']}: "
               f"cold={row['cold_create_s'] * 1e3:.0f}ms "

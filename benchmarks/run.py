@@ -25,11 +25,7 @@ def main() -> None:
 
     from benchmarks import (churn_scale, fig1_burst, fig7_coldstart,
                             fig8_warmstart, fig9_10_azure, fig11_failover,
-                            registration, scalability)
-    try:
-        from benchmarks import kernel_bench
-    except Exception:
-        kernel_bench = None
+                            kernel_bench, registration, scalability)
     modules = {
         "fig1": fig1_burst,
         "fig7": fig7_coldstart,
@@ -39,14 +35,14 @@ def main() -> None:
         "registration": registration,
         "scalability": scalability,
         "churn": churn_scale,
+        "kernels": kernel_bench,
     }
-    if kernel_bench is not None:
-        modules["kernels"] = kernel_bench
     only = set(filter(None, args.only.split(",")))
     skip = set(filter(None, args.skip.split(",")))
 
     rep = CsvReporter()
     rep.header()
+    failed = []
     for name, mod in modules.items():
         if only and name not in only:
             continue
@@ -60,6 +56,9 @@ def main() -> None:
             print(f"# {name} FAILED: {type(e).__name__}: {e}", flush=True)
             import traceback
             traceback.print_exc()
+            failed.append(name)
+    if failed:
+        sys.exit(f"# failed modules: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

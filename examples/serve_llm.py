@@ -21,6 +21,7 @@ import time
 
 import jax
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config
 from repro.core import Cluster, Function, ScalingConfig
 from repro.core.request import LiveRequest
@@ -30,6 +31,7 @@ from repro.simcore import Environment
 
 
 def main() -> None:
+    enable_compile_cache()
     cfg = get_config("smollm-360m").reduced(
         n_layers=4, d_model=128, n_heads=4, d_ff=256, vocab=1024)
     probe = Replica(cfg, max_seq=96)
@@ -53,7 +55,10 @@ def main() -> None:
     invs = [cluster.invoke("llm", exec_time=0.05,
                            request=LiveRequest(prompt=p, max_new_tokens=8))
             for p in prompts]
-    env.run(until=env.now + 30.0)
+    # live mode bills real wall time to the virtual clock, so run until
+    # every invocation has an outcome rather than to a fixed horizon
+    while any(i.t_done < 0 for i in invs):
+        env.run(until=env.now + 1.0)
     wall = time.perf_counter() - t_wall
 
     starts = backend.start_log

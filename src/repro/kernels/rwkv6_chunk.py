@@ -19,6 +19,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+_EXACT = jax.lax.Precision.HIGHEST
+
 
 def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, y_ref, sout_ref,
                 state_scr, *, chunk: int, n_chunks: int):
@@ -32,10 +34,15 @@ def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, y_ref, sout_ref,
     k = k_ref[0, 0].astype(jnp.float32)
     v = v_ref[0, 0].astype(jnp.float32)          # (C, dv)
     w = w_ref[0, 0].astype(jnp.float32)          # (C, dk) decay in (0,1)
-    u = u_ref[0].astype(jnp.float32)             # (dk,)
+    u = u_ref[0].astype(jnp.float32)             # (1, dk)
 
     logw = jnp.log(w)
-    cum = jnp.cumsum(logw, axis=0)               # cum_{i+1}
+    ii = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    jj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    # prefix sum as a lower-triangular matmul (Mosaic has no cumsum)
+    tril = jnp.where(jj <= ii, 1.0, 0.0)
+    cum = jax.lax.dot_general(tril, logw, (((1,), (0,)), ((), ())),
+                              precision=_EXACT)   # cum_{i+1}
     cum_in = cum - logw                          # cum_i
     cum_last = cum[-1:, :]                       # (1, dk)
 
@@ -44,10 +51,8 @@ def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, y_ref, sout_ref,
     k_rem = k * jnp.exp(cum_last - cum)
 
     scores = jax.lax.dot_general(q_dec, k_dec, (((1,), (1,)), ((), ())))
-    ii = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
-    jj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
     scores = jnp.where(jj < ii, scores, 0.0)     # strict lower triangle
-    bonus = jnp.sum(r * (u[None, :] * k), axis=-1)   # (C,)
+    bonus = jnp.sum(r * (u * k), axis=-1)          # (C,)
     scores = scores + jnp.where(jj == ii, bonus[:, None], 0.0)
 
     S = state_scr[...]                           # (dk, dv)
@@ -88,7 +93,9 @@ def wkv6_pallas(r, k, v, w, u, initial_state=None, chunk: int = 64,
             pl.BlockSpec((1, 1, chunk, dk), lambda b, h, ic: (b, h, ic, 0)),
             pl.BlockSpec((1, 1, chunk, dv), lambda b, h, ic: (b, h, ic, 0)),
             pl.BlockSpec((1, 1, chunk, dk), lambda b, h, ic: (b, h, ic, 0)),
-            pl.BlockSpec((1, dk), lambda b, h, ic: (h, 0)),
+            # (1, 1, dk) of (H, 1, dk): the last two block dims equal the
+            # array's, which the TPU tiling rule accepts for any dk
+            pl.BlockSpec((1, 1, dk), lambda b, h, ic: (h, 0, 0)),
             pl.BlockSpec((1, 1, dk, dv), lambda b, h, ic: (b, h, 0, 0)),
         ],
         out_specs=[
@@ -101,5 +108,5 @@ def wkv6_pallas(r, k, v, w, u, initial_state=None, chunk: int = 64,
         ],
         scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
         interpret=interpret,
-    )(rt, kt, vt, wt, u, initial_state)
+    )(rt, kt, vt, wt, u.reshape(H, 1, dk), initial_state)
     return y.transpose(0, 2, 1, 3), s_fin

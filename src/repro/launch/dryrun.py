@@ -26,9 +26,7 @@ import re
 import time
 import traceback
 
-
 from repro.launch.hlo_parse import parse_collectives  # noqa: E402
-from repro.models.sharding import use_mesh  # noqa: E402
 
 
 def _probe_variants(cfg):
@@ -149,7 +147,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str,
            "tag": tag}
     try:
         # -- full-depth compile: feasibility proof + memory analysis ------
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             base_rc = default_run_config(mesh, shape, **run_overrides)
             fn, args, meta = build_step(arch, shape_name, mesh,
                                         run_cfg=base_rc)
@@ -180,7 +178,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str,
                                         q_chunk=max(seq_full, 128),
                                         kv_chunk=max(seq_full, 128),
                                         seq_chunk=512))
-                with use_mesh(mesh):
+                with jax.set_mesh(mesh):
                     pfn, pargs, _ = build_step(arch, shape_name, mesh,
                                                run_cfg=run_cfg,
                                                cfg_override=pcfg)

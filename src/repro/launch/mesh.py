@@ -12,20 +12,20 @@ from __future__ import annotations
 
 import jax
 
-from repro.models.sharding import compat_make_mesh
+from repro.models.sharding import auto_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_local_mesh(n_model: int = 1):
     """Small mesh over however many real devices exist (tests/examples)."""
     n = len(jax.devices())
     n_model = min(n_model, n)
-    return compat_make_mesh((n // n_model, n_model), ("data", "model"))
+    return auto_mesh((n // n_model, n_model), ("data", "model"))
 
 
 def mesh_axis_names(mesh) -> tuple:

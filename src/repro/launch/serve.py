@@ -17,6 +17,7 @@ import time
 import jax
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config
 from repro.core import Cluster, Function, ScalingConfig
 from repro.serving.engine import Replica
@@ -37,6 +38,7 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_config(args.arch).reduced(
         n_layers=4, d_model=128, n_heads=4, d_ff=256, vocab=1024)
     replicas = {}

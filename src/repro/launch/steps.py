@@ -18,7 +18,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import get_config, SHAPES
 from repro.configs.base import ArchConfig, ShapeSpec
 from repro.models.api import RunConfig, build_model
-from repro.models.sharding import filter_spec, use_mesh
+from repro.models.sharding import filter_spec
 from repro.train.optimizer import (adamw_init_specs, adamw_pspecs,
                                    adamw_update)
 from repro.train.train_step import make_train_step
@@ -29,7 +29,7 @@ def _shard(mesh, spec_tree):
     def conv(s):
         fs = filter_spec(s)
         return NamedSharding(mesh, fs if fs is not None else s)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         return jax.tree.map(conv, spec_tree,
                             is_leaf=lambda x: isinstance(x, P))
 

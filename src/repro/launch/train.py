@@ -19,7 +19,6 @@ from repro.configs import get_config
 from repro.launch.mesh import make_local_mesh
 from repro.launch.steps import default_run_config
 from repro.models.api import build_model
-from repro.models.sharding import use_mesh
 from repro.train.checkpoint import (latest_step, restore_checkpoint,
                                     save_checkpoint)
 from repro.train.data import ZipfLMStream
@@ -50,7 +49,7 @@ def main() -> None:
         cfg = cfg.reduced(n_layers=4, d_model=128, n_heads=4, d_ff=384,
                           vocab=2048)
     mesh = make_local_mesh()
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         run = default_run_config(
             mesh, None, q_chunk=64, kv_chunk=64, seq_chunk=16,
             grad_accum=args.grad_accum, use_zero1=args.zero1,

@@ -11,7 +11,8 @@ Two execution modes, selected per function by ``LiveFunctionSpec.mode``:
     (repro/live/container.py): spawn + import + replica build, hundreds of
     ms to seconds, the containerd analogue. Its executables cannot be
     shared in-process; the JAX *persistent* compilation cache directory
-    plays the shared-cache role across worker processes instead.
+    plays the shared-cache role across worker processes instead. CPU only:
+    on a TPU the parent holds the chip, so container mode refuses to start.
 
 Wiring into the DES (all hooks are no-ops unless a backend is installed —
 the default path stays bit-identical):
@@ -109,15 +110,13 @@ class LiveBackend:
 
     def __init__(self, specs: Optional[Dict[str, LiveFunctionSpec]] = None,
                  default_spec: Optional[LiveFunctionSpec] = None,
-                 exec_cache=None, compile_cache_dir: Optional[str] = None):
+                 exec_cache=None):
         from repro.serving.exec_cache import default_cache
 
         self.specs = dict(specs or {})
         self.default_spec = default_spec
         self.exec_cache = exec_cache if exec_cache is not None \
             else default_cache()
-        # container-mode persistent XLA cache dir (shared across workers)
-        self.compile_cache_dir = compile_cache_dir
         self.sandboxes: Dict[int, object] = {}       # sid -> runtime
         # results that outlive their runtime (graceful teardown drains
         # in-slot requests; their tickets must still collect)
@@ -158,8 +157,7 @@ class LiveBackend:
         if spec.mode == "container":
             from repro.live.container import ContainerSandbox
 
-            rt = ContainerSandbox(spec, cache_dir=self.compile_cache_dir,
-                                  seed=sandbox.sandbox_id)
+            rt = ContainerSandbox(spec, seed=sandbox.sandbox_id)
             cold = rt.cold
         else:
             rt = _ProcessSandbox(spec, self.exec_cache,

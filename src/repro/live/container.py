@@ -7,13 +7,20 @@ with a tiny admit/collect protocol mirroring ``_ProcessSandbox``.
 
 An in-process ``ExecutableCache`` cannot help across process boundaries,
 so the shared-executable story here is the JAX *persistent compilation
-cache*: the parent passes ``cache_dir`` and every child points
-``jax_compilation_cache_dir`` at it. The first worker of a config pays the
-XLA compile and populates the directory; later workers (the "warm
-container" path) deserialize the executable instead of recompiling — the
-same cold/warm split the in-process cache gives, at container granularity.
+cache* (``repro.compile_cache``), which every child opens at the same
+directory. The first worker of a config pays the XLA compile and populates
+it; later workers (the "warm container" path) deserialize the executable
+instead of recompiling — the same cold/warm split the in-process cache
+gives, at container granularity. A child reports how many executables it
+loaded from that cache, and a creation is cold when it loaded none.
+
+One process per chip: a parent that has touched JAX holds the TPU, and a
+spawned child then either fails on the TPU library's lock or quietly starts
+a CPU backend. So container mode refuses to start under a TPU backend, and
+every child checks that it runs on the parent's platform.
 
 Protocol (parent -> child):
+    (startup)                    -> ("ready", cache_hits) | ("error", why)
     ("admit", prompt, max_new)   -> ("rid", rid, peers)
     ("collect", rid)             -> ("done", tokens_or_None, peers)
     ("shutdown", drain)          -> ("bye", finished_dict)
@@ -29,15 +36,33 @@ from typing import Dict, List, Optional, Tuple
 _READY_TIMEOUT_S = 120.0
 
 
-def _child_main(conn, spec, cache_dir: Optional[str], seed: int) -> None:
+def _child_main(conn, spec, seed: int, platform: str,
+                persistent_cache: bool) -> None:
     """Subprocess entry point (module-level: spawn must import it)."""
     import jax
 
-    if cache_dir:
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
+    if jax.default_backend() != platform:
+        conn.send(("error", f"container worker started on "
+                            f"{jax.default_backend()}, parent runs on "
+                            f"{platform}"))
+        conn.close()
+        return
+    if persistent_cache:
+        from repro.compile_cache import enable_compile_cache
+
+        enable_compile_cache()
+        # keep the small compiles too (JAX skips those under a second)
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        jax.config.update(
-            "jax_persistent_cache_min_entry_size_bytes", -1)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    else:
+        jax.config.update("jax_enable_compilation_cache", False)
+    hits = []
+
+    def count_hit(event: str, **_) -> None:
+        if event == "/jax/compilation_cache/cache_hits":
+            hits.append(event)
+
+    jax.monitoring.register_event_listener(count_hit)
     from repro.serving.engine import ContinuousBatcher, Replica
     from repro.serving.exec_cache import ExecutableCache
 
@@ -51,7 +76,7 @@ def _child_main(conn, spec, cache_dir: Optional[str], seed: int) -> None:
     warm_rid = batcher.add_request([1], 1)
     batcher.run_until_done()
     batcher.finished.pop(warm_rid, None)
-    conn.send(("ready",))
+    conn.send(("ready", len(hits)))
     while True:
         msg = conn.recv()
         if msg[0] == "admit":
@@ -82,26 +107,36 @@ def _child_main(conn, spec, cache_dir: Optional[str], seed: int) -> None:
 class ContainerSandbox:
     """Parent-side handle; API mirrors ``_ProcessSandbox``."""
 
-    def __init__(self, spec, cache_dir: Optional[str] = None, seed: int = 0):
-        import os
+    def __init__(self, spec, seed: int = 0, persistent_cache: bool = True):
+        import jax
 
+        platform = jax.default_backend()
+        if platform == "tpu":
+            raise RuntimeError(
+                "container mode cannot run on a TPU: this process holds the "
+                "chip, and a spawned worker can neither share it nor take "
+                "it; serve live functions in process mode on a chip")
         self.spec = spec
-        # cold = nothing persisted yet for any config (first worker pays
-        # the compile and populates the directory)
-        self.cold = (not cache_dir) or not os.path.isdir(cache_dir) \
-            or not os.listdir(cache_dir)
         ctx = mp.get_context("spawn")
         self._conn, child_conn = ctx.Pipe()
-        self.proc = ctx.Process(target=_child_main,
-                                args=(child_conn, spec, cache_dir, seed),
-                                daemon=True)
+        self.proc = ctx.Process(
+            target=_child_main,
+            args=(child_conn, spec, seed, platform, persistent_cache),
+            daemon=True)
         t0 = time.perf_counter()
         self.proc.start()
         child_conn.close()
-        if not self._conn.poll(_READY_TIMEOUT_S):
+        msg = ("error", "container worker never became ready")
+        if self._conn.poll(_READY_TIMEOUT_S):
+            try:
+                msg = self._conn.recv()
+            except EOFError:
+                msg = ("error", "container worker exited during startup")
+        if msg[0] != "ready":
             self.proc.kill()
-            raise RuntimeError("container worker never became ready")
-        assert self._conn.recv()[0] == "ready"
+            raise RuntimeError(msg[1])
+        # cold = the worker compiled its executables instead of loading them
+        self.cold = msg[1] == 0
         self.start_wall_s = time.perf_counter() - t0
         self._finished: Dict[int, List[int]] = {}
 

@@ -24,7 +24,7 @@ from repro.configs.base import ArchConfig
 class RunConfig:
     """Execution knobs (orthogonal to the architecture)."""
 
-    attn_impl: str = "jnp"        # jnp | pallas | reference
+    attn_impl: str = "jnp"        # jnp | reference (see build_model)
     q_chunk: int = 512
     kv_chunk: int = 1024
     layer_mode: str = "scan"      # scan | unroll (unroll => exact HLO costs)
@@ -44,19 +44,20 @@ class RunConfig:
 
 def build_model(cfg: ArchConfig, run_cfg: Optional[RunConfig] = None):
     run_cfg = run_cfg or RunConfig()
-    if cfg.family in ("dense", "vlm"):
-        from repro.models.transformer import DecoderLM
-        return DecoderLM(cfg, run_cfg)
-    if cfg.family == "moe":
-        from repro.models.transformer import DecoderLM
-        return DecoderLM(cfg, run_cfg)
-    if cfg.family == "ssm":
-        from repro.models.rwkv import RWKV6Model
-        return RWKV6Model(cfg, run_cfg)
-    if cfg.family == "hybrid":
-        from repro.models.ssm import Zamba2Model
-        return Zamba2Model(cfg, run_cfg)
-    if cfg.family == "audio":
-        from repro.models.whisper import WhisperModel
-        return WhisperModel(cfg, run_cfg)
-    raise ValueError(f"unknown family {cfg.family}")
+    if cfg.family in ("dense", "vlm", "moe"):
+        from repro.models.transformer import DecoderLM as cls
+    elif cfg.family == "ssm":
+        from repro.models.rwkv import RWKV6Model as cls
+    elif cfg.family == "hybrid":
+        from repro.models.ssm import Zamba2Model as cls
+    elif cfg.family == "audio":
+        from repro.models.whisper import WhisperModel as cls
+    else:
+        raise ValueError(f"unknown family {cfg.family}")
+    # a value the model does not implement must fail here, not run another
+    # attention form under its name
+    impls = getattr(cls, "ATTN_IMPLS", ("jnp",))
+    if run_cfg.attn_impl not in impls:
+        raise ValueError(f"{cfg.name} implements attn_impl {impls}, "
+                         f"not {run_cfg.attn_impl!r}")
+    return cls(cfg, run_cfg)

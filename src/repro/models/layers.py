@@ -1,10 +1,9 @@
 """Shared model components: norms, rotary embeddings, attention, MLPs.
 
-All functions are pure JAX (jnp + lax) so they lower for any backend; the
-attention entry points can route to Pallas TPU kernels (repro.kernels) when
-``impl="pallas"`` — the default ``impl="jnp"`` uses the same blocked online-
-softmax algorithm written with ``lax.scan`` so the dry-run HLO is portable
-and memory-bounded (no S×S score materialization at 32 K context).
+All functions are pure JAX (jnp + lax) so they lower for any backend. The
+blocked online-softmax attention is written with ``lax.scan`` so the dry-run
+HLO is portable and memory-bounded (no S×S score materialization at 32 K
+context). The Pallas TPU kernels in ``repro.kernels`` are not wired in.
 
 Sharding is expressed with ``jax.lax.with_sharding_constraint`` using
 PartitionSpecs built from logical axis names; see models/api.py.
@@ -24,7 +23,9 @@ from jax import lax
 # -- initializers -------------------------------------------------------------
 
 def dense_init(key, shape, dtype=jnp.float32, scale: Optional[float] = None):
-    fan_in = shape[0] if len(shape) >= 2 else 1
+    """Normal with std 1/sqrt(fan_in) for a (..., d_in, d_out) weight; the
+    leading axes are stacked layers or experts, not inputs."""
+    fan_in = shape[-2] if len(shape) >= 2 else 1
     scale = scale if scale is not None else 1.0 / math.sqrt(max(fan_in, 1))
     return (jax.random.normal(key, shape) * scale).astype(dtype)
 

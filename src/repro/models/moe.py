@@ -34,7 +34,6 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ArchConfig
-from repro.models.sharding import compat_shard_map, get_abstract_mesh
 
 
 def _dt(cfg):
@@ -142,7 +141,7 @@ def moe_ffn(cfg: ArchConfig, run_cfg, w, x) -> jax.Array:
     e = cfg.moe
     m = run_cfg.model_axis
     dax = run_cfg.data_axes
-    mesh = get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     axis_sizes = dict(zip(mesh.axis_names, mesh.axis_sizes)) if mesh.axis_names else {}
     n_shards = axis_sizes.get(m, 1)
     B, S, D = x.shape
@@ -179,8 +178,9 @@ def moe_ffn(cfg: ArchConfig, run_cfg, w, x) -> jax.Array:
         in_specs = (P(dax_present, None, None),
                     {"router": P(None, None), "e_gate": P(m, None, fa),
                      "e_up": P(m, None, fa), "e_down": P(m, fa, None)})
-        y = compat_shard_map(per_shard, mesh=mesh, in_specs=in_specs,
-                             out_specs=P(dax_present, None, None))(x, moe_w)
+        y = jax.shard_map(per_shard, mesh=mesh, in_specs=in_specs,
+                          out_specs=P(dax_present, None, None),
+                          check_vma=False)(x, moe_w)
 
     if e.dense_residual_ff:
         from repro.models.layers import swiglu

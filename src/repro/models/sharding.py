@@ -11,59 +11,20 @@ from typing import Optional
 
 import jax
 from jax import lax
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AxisType, PartitionSpec as P
 
 
-def compat_make_mesh(axis_shapes, axis_names):
-    """``jax.make_mesh`` across versions: newer JAX wants explicit
-    ``axis_types`` (Auto) for the models' mixed auto/explicit sharding; older
-    JAX (<= 0.4.x) has no ``axis_types`` kwarg and no ``AxisType`` enum."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(axis_shapes, axis_names,
-                             axis_types=(axis_type.Auto,) * len(axis_names))
-    return jax.make_mesh(axis_shapes, axis_names)
-
-
-def use_mesh(mesh):
-    """Context manager activating ``mesh``: ``jax.set_mesh`` when available,
-    else the mesh's own (legacy) context-manager protocol."""
-    fn = getattr(jax, "set_mesh", None)
-    return fn(mesh) if fn is not None else mesh
-
-
-def compat_shard_map(f, mesh, in_specs, out_specs):
-    """``shard_map`` across versions: newer JAX exposes ``jax.shard_map``
-    with ``check_vma``; 0.4.x has ``jax.experimental.shard_map.shard_map``
-    with ``check_rep``. Replication checking is off either way — the models
-    rely on manual psum merges the checker can't see through."""
-    fn = getattr(jax, "shard_map", None)
-    if fn is None:
-        from jax.experimental.shard_map import shard_map as fn
-    try:
-        return fn(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=False)
-    except TypeError:
-        return fn(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
-
-
-def get_abstract_mesh():
-    """``jax.sharding.get_abstract_mesh`` with a fallback for JAX versions
-    that predate it: returns the ambient physical mesh entered via ``with
-    mesh:`` (an empty ``Mesh`` — ``axis_names == ()`` — when there is none).
-    Both return types expose ``axis_names`` / ``axis_sizes``, which is all
-    the model code reads."""
-    fn = getattr(jax.sharding, "get_abstract_mesh", None)
-    if fn is not None:
-        return fn()
-    from jax.interpreters import pxla
-    return pxla.thread_resources.env.physical_mesh
+def auto_mesh(axis_shapes, axis_names):
+    """``jax.make_mesh`` with every axis ``Auto``. JAX's default axis type is
+    ``Explicit``; the models mix automatic sharding (``constrain``) with
+    ``jax.shard_map`` regions, which needs Auto axes."""
+    return jax.make_mesh(axis_shapes, axis_names,
+                         axis_types=(AxisType.Auto,) * len(axis_names))
 
 
 def mesh_axis_sizes() -> dict:
-    mesh = get_abstract_mesh()
-    if mesh is None or not mesh.axis_names:
+    mesh = jax.sharding.get_abstract_mesh()
+    if not mesh.axis_names:
         return {}
     return dict(zip(mesh.axis_names, mesh.axis_sizes))
 

@@ -37,6 +37,9 @@ def _dt(cfg: ArchConfig):
 
 
 class DecoderLM:
+    # "reference" swaps the blocked prefill attention for the O(S^2) oracle
+    ATTN_IMPLS = ("jnp", "reference")
+
     def __init__(self, cfg: ArchConfig, run_cfg: RunConfig):
         self.cfg = cfg
         self.run = run_cfg

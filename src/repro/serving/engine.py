@@ -41,13 +41,16 @@ def sample_token(logits: jax.Array, rng: Optional[jax.Array] = None,
     return jax.random.categorical(rng, logits).astype(jnp.int32)
 
 
+# execution knobs every replica uses unless its function spec says otherwise
+SERVE_RUN_CFG = RunConfig(q_chunk=64, kv_chunk=64, seq_chunk=16)
+
+
 class Replica:
     def __init__(self, cfg: ArchConfig, params=None, rng_seed: int = 0,
                  max_seq: int = 256, run_cfg: Optional[RunConfig] = None,
                  exec_cache: Optional[ExecutableCache] = None):
         self.cfg = cfg
-        self.run_cfg = run_cfg or RunConfig(q_chunk=64, kv_chunk=64,
-                                            seq_chunk=16)
+        self.run_cfg = run_cfg or SERVE_RUN_CFG
         # prefill and decode executables come from the shared process-global
         # cache: the second replica of a (cfg, run_cfg) pays model-state
         # construction but zero XLA recompilation (serving/exec_cache.py)

@@ -13,7 +13,7 @@ from repro.launch.hlo_parse import parse_collectives
 from repro.launch.mesh import make_local_mesh
 from repro.launch.steps import build_step, default_run_config
 from repro.models.api import RunConfig, build_model
-from repro.models.sharding import filter_spec, use_mesh
+from repro.models.sharding import filter_spec
 
 
 def test_applicable_shapes_policy():
@@ -34,7 +34,7 @@ def test_build_step_reduced_on_local_mesh():
     mesh = make_local_mesh()
     cfg = get_config("qwen3-32b").reduced()
     shape = ShapeSpec("t", 64, 4, "train")
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         run = default_run_config(mesh, shape, q_chunk=16, kv_chunk=16)
         model = build_model(cfg, run)
         # spec trees are structurally consistent

@@ -294,11 +294,16 @@ def test_scale_to_zero_reclaims_live_replicas():
 
 # -- container mode (subprocess worker; slower, one spawn) --------------------
 
-def test_container_sandbox_roundtrip(tmp_path):
-    spec = LiveFunctionSpec(cfg=TINY, mode="container", max_seq=64,
+def _container_spec() -> LiveFunctionSpec:
+    return LiveFunctionSpec(cfg=TINY, mode="container", max_seq=64,
                             max_slots=2, default_max_new=3)
-    lb = LiveBackend(default_spec=spec,
-                     compile_cache_dir=str(tmp_path / "xla"))
+
+
+def test_container_sandbox_roundtrip(tmp_path, monkeypatch):
+    # spawned workers inherit the environment: keep their persistent
+    # compile cache in the test's own directory
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "xla"))
+    lb = LiveBackend(default_spec=_container_spec())
     lb.create_hook(_sandbox(1))
     try:
         assert lb.start_log[0]["mode"] == "container"
@@ -308,3 +313,44 @@ def test_container_sandbox_roundtrip(tmp_path):
     finally:
         lb.close()
     assert lb.replicas_live == 0
+
+
+def test_container_cold_warm_follows_persistent_cache(tmp_path, monkeypatch):
+    """A worker is cold when it compiled, warm when it loaded its executables
+    from the persistent cache an earlier worker filled; a worker with the
+    cache off always compiles."""
+    from repro.live.container import ContainerSandbox
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "xla"))
+    colds = []
+    for persistent in (True, True, False):
+        sb = ContainerSandbox(_container_spec(), seed=1,
+                              persistent_cache=persistent)
+        colds.append(sb.cold)
+        sb.close()
+    assert colds == [True, False, True]
+
+
+def test_container_sandbox_refuses_tpu(monkeypatch):
+    """One process per chip: a parent on a TPU backend holds the chip, so a
+    spawned worker could not run there. Refuse before spawning."""
+    import jax
+
+    from repro.live.container import ContainerSandbox
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="TPU"):
+        ContainerSandbox(_container_spec())
+
+
+def test_container_worker_rejects_other_platform():
+    """A worker that comes up on another platform than its parent reports
+    an error instead of serving from the wrong device."""
+    import multiprocessing as mp
+
+    from repro.live.container import _child_main
+
+    parent, child = mp.Pipe()
+    _child_main(child, _container_spec(), 0, "tpu", True)
+    msg = parent.recv()
+    assert msg[0] == "error" and "parent runs on tpu" in msg[1]

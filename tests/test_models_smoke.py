@@ -106,3 +106,20 @@ def test_param_counts_match_assignment():
 def test_moe_active_params():
     kimi = get_config("kimi-k2-1t-a32b")
     assert 25 <= kimi.n_active_params / 1e9 <= 40     # "a32b"
+
+
+@pytest.mark.parametrize("arch", ARCHS)
+def test_unimplemented_attn_impl_raises(arch):
+    """No model runs another attention form under a name it does not
+    implement; "pallas" was once accepted and silently ran the jnp form."""
+    with pytest.raises(ValueError, match="attn_impl"):
+        build_model(get_config(arch).reduced(),
+                    RunConfig(attn_impl="pallas"))
+
+
+def test_dense_init_fan_in_is_the_input_width():
+    """Stacked (L, d_in, d_out) weights are scaled by d_in, not by L."""
+    from repro.models.layers import dense_init
+
+    w = dense_init(jax.random.PRNGKey(0), (2, 900, 64))
+    assert abs(float(jnp.std(w)) - 1 / 30) < 1e-3

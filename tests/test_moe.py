@@ -109,9 +109,9 @@ def test_ep_shard_invariance_subprocess():
         params = model.init_params(jax.random.PRNGKey(0))
         batch = {"tokens": jnp.arange(64, dtype=jnp.int32).reshape(2, 32) % 100}
         y1 = model.forward(params, batch)          # no mesh: local path
-        from repro.models.sharding import compat_make_mesh, use_mesh
-        mesh = compat_make_mesh((1, 4), ("data", "model"))
-        with use_mesh(mesh):
+        from repro.models.sharding import auto_mesh
+        mesh = auto_mesh((1, 4), ("data", "model"))
+        with jax.set_mesh(mesh):
             y4 = jax.jit(model.forward)(params, batch)
         err = float(jnp.abs(y1 - y4).max())
         assert err < 2e-2, f"EP shard mismatch: {err}"
@@ -145,9 +145,9 @@ def test_sharded_decode_subprocess():
         batch = {"tokens": jnp.ones((B, 1), jnp.int32),
                  "cache_len": jnp.array(3, jnp.int32)}
         l_ref, _ = jax.jit(m0.decode_step)(params, cache, batch)
-        from repro.models.sharding import compat_make_mesh, use_mesh
-        mesh = compat_make_mesh((2, 4), ("data", "model"))
-        with use_mesh(mesh):
+        from repro.models.sharding import auto_mesh
+        mesh = auto_mesh((2, 4), ("data", "model"))
+        with jax.set_mesh(mesh):
             m1 = build_model(cfg, RunConfig(q_chunk=16, kv_chunk=16,
                                             data_axes=("data",),
                                             sharded_decode=True))

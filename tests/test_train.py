@@ -120,8 +120,8 @@ def test_elastic_restore_resharding(tmp_path):
     from jax.sharding import NamedSharding, PartitionSpec as P
     model, params, opt, step = _setup()
     save_checkpoint(str(tmp_path), 2, {"params": params})
-    from repro.models.sharding import compat_make_mesh
-    mesh = compat_make_mesh((1, 1), ("data", "model"))
+    from repro.models.sharding import auto_mesh
+    mesh = auto_mesh((1, 1), ("data", "model"))
     shardings = jax.tree.map(
         lambda sp: NamedSharding(mesh, sp),
         model.param_pspecs(),
