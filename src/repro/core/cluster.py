@@ -55,6 +55,7 @@ from __future__ import annotations
 
 import heapq
 import itertools
+import time
 from typing import Callable, Dict, Generator, List, Optional
 
 from repro.core.abstractions import DataPlaneInfo, Function, WorkerNodeInfo
@@ -415,6 +416,10 @@ class Cluster:
         """Submit an invocation at env.now; returns the Invocation record.
         ``request`` (a ``LiveRequest``) rides the invocation to whatever
         sandbox the DP picks and is executed there by the live backend."""
+        if request is not None:
+            # a host stamp on the payload's request, for its wall-clock
+            # waits; virtual time is untouched
+            request.t_submit = time.perf_counter()  # simlint: ok(wall-clock): live request stamp, never sim state
         inv = Invocation(inv_id=next(self._inv_ids),
                          function_name=function_name,
                          arrival=self.env.now, exec_time=exec_time,

@@ -156,6 +156,19 @@ def render_metrics(cluster: "Cluster") -> str:
         lines.append(f"dirigent_live_invocations_total {lb.invokes}")
         lines.append("# TYPE dirigent_live_tokens_total counter")
         lines.append(f"dirigent_live_tokens_total {lb.tokens_total}")
+        # the batchers' decode steps: slot occupancy is slot_steps over
+        # slot_capacity_steps, and prompt_tokens over slot_steps is the share
+        # of occupied slots that fed a prompt token and generated nothing
+        counts = lb.batcher_counts()
+        for key, family in (
+                ("steps", "dirigent_live_decode_steps_total"),
+                ("slot_steps", "dirigent_live_slot_steps_total"),
+                ("capacity_steps", "dirigent_live_slot_capacity_steps_total"),
+                ("prompt_tokens", "dirigent_live_prompt_tokens_total"),
+                ("generated_tokens",
+                 "dirigent_live_generated_tokens_total")):
+            lines.append(f"# TYPE {family} counter")
+            lines.append(f"{family} {counts[key]}")
     return "\n".join(lines) + "\n"
 
 

@@ -20,7 +20,12 @@ class LiveRequest:
     to the worker's ``live_backend`` for slot admission + shared decode
     (repro/live/backend.py). ``wall_s`` is the payload wall time billed to
     the sim clock; ``batched_with`` counts how many other requests shared
-    at least one decode step in the same replica's batcher slots."""
+    at least one decode step in the same replica's batcher slots.
+
+    The ``t_*`` fields are host ``perf_counter`` stamps, never sim time:
+    submitted at the front end, admitted into a slot, first and last output
+    token (an in-process batcher only), and ``collect`` returned. ``None``
+    where the request never got there."""
 
     prompt: list = field(default_factory=list)     # token ids
     max_new_tokens: int = 16
@@ -30,6 +35,11 @@ class LiveRequest:
     batched_with: int = 0
     failed: bool = False
     failure_reason: str = ""
+    t_submit: Optional[float] = None
+    t_admit: Optional[float] = None
+    t_first: Optional[float] = None
+    t_last: Optional[float] = None
+    t_collected: Optional[float] = None
 
 
 @dataclass

@@ -30,12 +30,23 @@ the default path stays bit-identical):
     decode steps; ``collect`` then pumps the batcher until the request's
     slot finishes, billing only the wall time this request actually spent
     pumping (work done while pumping for a neighbour is the batching win).
+
+Spans (``jax.profiler.TraceAnnotation``, free when no profiler runs):
+``live.admit`` and ``live.pump`` on the invoke path, ``live.create.params``,
+``live.create.cache`` and ``live.create.warm`` in a process-mode creation.
+Each ``LiveRequest`` gets ``perf_counter`` stamps: ``t_admit`` here,
+``t_first``/``t_last`` from the batcher (process mode) and ``t_collected``
+when ``collect`` returns.
 """
 from __future__ import annotations
 
+import contextlib
 import time
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
+
+import jax
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ArchConfig, ShapeSpec
 from repro.core.request import LiveRequest
@@ -64,17 +75,29 @@ class LiveTicket:
     admit_peers: int = 0             # active slots present at admission
 
 
+# the batcher counters LiveBackend.batcher_counts sums
+BATCHER_COUNTS = ("steps", "slot_steps", "capacity_steps", "prompt_tokens",
+                  "generated_tokens")
+
+
+@contextlib.contextmanager
+def _phase(name: str, sandbox_id: int, into: Dict[str, float]):
+    """Span ``live.create.<name>``; its wall seconds go to ``<name>_s``."""
+    t0 = time.perf_counter()
+    with TraceAnnotation(f"live.create.{name}", sandbox=sandbox_id):
+        yield
+    into[f"{name}_s"] = round(time.perf_counter() - t0, 6)
+
+
 class _ProcessSandbox:
     """In-process replica + batcher (mode="process")."""
 
-    def __init__(self, spec: LiveFunctionSpec, exec_cache, seed: int):
-        from repro.serving.engine import ContinuousBatcher, Replica
-
+    def __init__(self, sandbox_id: int, spec: LiveFunctionSpec, replica,
+                 batcher):
+        self.sandbox_id = sandbox_id
         self.spec = spec
-        self.replica = Replica(spec.cfg, rng_seed=seed, max_seq=spec.max_seq,
-                               run_cfg=spec.run_cfg, exec_cache=exec_cache)
-        self.batcher = ContinuousBatcher(self.replica,
-                                         max_slots=spec.max_slots)
+        self.replica = replica
+        self.batcher = batcher
 
     def admit(self, req: LiveRequest) -> Tuple[int, int]:
         """Admit into a free slot; returns (rid, co-resident active slots)."""
@@ -86,14 +109,29 @@ class _ProcessSandbox:
     def pump(self, rid: int) -> Tuple[Optional[List[int]], int]:
         """Step the shared batcher until ``rid`` finishes; returns (tokens,
         max co-resident active slots seen while pumping)."""
-        peers = 0
-        while rid not in self.batcher.finished:
-            active = sum(1 for s in self.batcher.slots if s.active)
-            if active == 0:
-                break                # aborted out from under us
-            peers = max(peers, active)
-            self.batcher.step()
+        peers = steps = 0
+        with TraceAnnotation("live.pump", sandbox=self.sandbox_id,
+                             rid=rid) as span:
+            while rid not in self.batcher.finished:
+                active = sum(1 for s in self.batcher.slots if s.active)
+                if active == 0:
+                    break                # aborted out from under us
+                peers = max(peers, active)
+                self.batcher.step()
+                steps += 1
+            span.set_metadata(steps=steps)
         return self.batcher.finished.get(rid), peers
+
+    def token_times(self, rid: int) -> Optional[Tuple[float, float]]:
+        """``perf_counter`` stamps of ``rid``'s first and last output token."""
+        return self.batcher.token_times.pop(rid, None)
+
+    def counts(self) -> Dict[str, int]:
+        b = self.batcher
+        return {"steps": b.steps, "slot_steps": b.slot_steps,
+                "capacity_steps": b.steps * b.max_slots,
+                "prompt_tokens": b.prompt_tokens,
+                "generated_tokens": b.generated_tokens}
 
     def drain(self) -> Dict[int, List[int]]:
         return self.batcher.run_until_done()
@@ -119,8 +157,9 @@ class LiveBackend:
             else default_cache()
         self.sandboxes: Dict[int, object] = {}       # sid -> runtime
         # results that outlive their runtime (graceful teardown drains
-        # in-slot requests; their tickets must still collect)
-        self._orphaned: Dict[Tuple[int, int], List[int]] = {}
+        # in-slot requests; their tickets must still collect): tokens and
+        # their first/last stamps
+        self._orphaned: Dict[Tuple[int, int], tuple] = {}
         self._failed_sids: set = set()               # torn down drain=False
         # -- observability (monitoring.render_metrics) ----------------------
         self.start_log: List[dict] = []              # one row per creation
@@ -129,6 +168,8 @@ class LiveBackend:
         self.invoke_seconds_total = 0.0
         self.tokens_total = 0
         self.batched_invokes = 0                     # shared >=1 decode step
+        # batcher counters of runtimes already torn down
+        self._retired_counts = dict.fromkeys(BATCHER_COUNTS, 0)
 
     # -- config ------------------------------------------------------------
     def spec_for(self, function_name: str) -> LiveFunctionSpec:
@@ -145,39 +186,66 @@ class LiveBackend:
     def cache_stats(self) -> dict:
         return self.exec_cache.stats()
 
+    def batcher_counts(self) -> Dict[str, int]:
+        """The ``BATCHER_COUNTS`` counters, summed over the in-process
+        batchers, live and torn down (a container's batcher lives in its
+        worker process and is not counted)."""
+        out = dict(self._retired_counts)
+        for rt in self.sandboxes.values():
+            if isinstance(rt, _ProcessSandbox):
+                for k, v in rt.counts().items():
+                    out[k] += v
+        return out
+
     # -- WorkerDaemon hooks --------------------------------------------------
     def create_hook(self, sandbox) -> None:
         """Build the real payload for a freshly booted sandbox. Wall time
         (and whether the executable cache was cold) lands in start_log —
         the measured per-phase costs the bench turns into a calibrated
-        DirigentCosts candidate."""
+        DirigentCosts candidate. A process-mode row also times its phases:
+        ``params_s`` (weights made and on the device), ``cache_s`` (the KV
+        cache) and ``warm_s`` (the decode executable traced or found); a
+        container's phases run in its worker and read ``None``."""
         spec = self.spec_for(sandbox.function_name)
+        sid = sandbox.sandbox_id
         t0 = time.perf_counter()
         misses0 = self.exec_cache.misses
+        phases = dict.fromkeys(("params_s", "cache_s", "warm_s"))
         if spec.mode == "container":
             from repro.live.container import ContainerSandbox
 
-            rt = ContainerSandbox(spec, seed=sandbox.sandbox_id)
+            rt = ContainerSandbox(spec, seed=sid)
             cold = rt.cold
         else:
-            rt = _ProcessSandbox(spec, self.exec_cache,
-                                 seed=sandbox.sandbox_id)
+            from repro.serving.engine import ContinuousBatcher, Replica
+
+            with _phase("params", sid, phases):
+                replica = Replica(spec.cfg, rng_seed=sid,
+                                  max_seq=spec.max_seq, run_cfg=spec.run_cfg,
+                                  exec_cache=self.exec_cache)
+                jax.block_until_ready(replica.params)
+            with _phase("cache", sid, phases):
+                batcher = ContinuousBatcher(replica, max_slots=spec.max_slots)
+                jax.block_until_ready(batcher.cache)
+            rt = _ProcessSandbox(sid, spec, replica, batcher)
             # bill the executable trace to creation (not the first invoke):
             # a cold cache compiles here; a warm one returns instantly
             shape = ShapeSpec("live", spec.max_seq, spec.max_slots, "decode")
-            compile_s = self.exec_cache.warm(spec.cfg, shape,
-                                             run_cfg=rt.replica.run_cfg,
-                                             params=rt.replica.params)
+            with _phase("warm", sid, phases):
+                compile_s = self.exec_cache.warm(spec.cfg, shape,
+                                                 run_cfg=replica.run_cfg,
+                                                 params=replica.params)
             # cold = this creation built the entry OR traced a new shape
             cold = self.exec_cache.misses > misses0 or compile_s > 0.0
-        self.sandboxes[sandbox.sandbox_id] = rt
-        self._failed_sids.discard(sandbox.sandbox_id)
+        self.sandboxes[sid] = rt
+        self._failed_sids.discard(sid)
         self.start_log.append({
-            "sandbox_id": sandbox.sandbox_id,
+            "sandbox_id": sid,
             "function": sandbox.function_name,
             "mode": spec.mode,
             "cold": cold,
             "wall_s": round(time.perf_counter() - t0, 6),
+            **phases,
         })
 
     def teardown_hook(self, sandbox_id: int, drain: bool = True) -> None:
@@ -190,10 +258,14 @@ class LiveBackend:
         self.teardowns += 1
         if drain:
             for rid, toks in rt.drain().items():
-                self._orphaned[(sandbox_id, rid)] = toks
+                self._orphaned[(sandbox_id, rid)] = (toks,
+                                                     rt.token_times(rid))
         else:
             rt.abort()
             self._failed_sids.add(sandbox_id)
+        if isinstance(rt, _ProcessSandbox):
+            for k, v in rt.counts().items():
+                self._retired_counts[k] += v
         rt.close()
 
     # -- invoke path ---------------------------------------------------------
@@ -201,7 +273,10 @@ class LiveBackend:
         rt = self.sandboxes.get(sandbox_id)
         if rt is None:
             raise RuntimeError(f"live sandbox {sandbox_id} gone")
-        rid, peers = rt.admit(req)
+        with TraceAnnotation("live.admit", sandbox=sandbox_id) as span:
+            rid, peers = rt.admit(req)
+            span.set_metadata(rid=rid)
+        req.t_admit = time.perf_counter()
         return LiveTicket(sandbox_id=sandbox_id, rid=rid, request=req,
                           admit_peers=peers)
 
@@ -214,11 +289,13 @@ class LiveBackend:
         key = (ticket.sandbox_id, ticket.rid)
         rt = self.sandboxes.get(ticket.sandbox_id)
         toks: Optional[List[int]] = None
+        times: Optional[Tuple[float, float]] = None
         peers = 0
         if key in self._orphaned:                # finished during teardown
-            toks = self._orphaned.pop(key)
+            toks, times = self._orphaned.pop(key)
         elif rt is not None:
             toks, peers = rt.pump(ticket.rid)
+            times = rt.token_times(ticket.rid)
         if toks is None:
             req.failed = True
             req.failure_reason = (
@@ -227,13 +304,16 @@ class LiveBackend:
                 else "request aborted")
         else:
             req.tokens = toks
+            if times is not None:
+                req.t_first, req.t_last = times
             # shared decode steps with: slots present when we were admitted
             # (we free-rode on their pump) or co-active while we pumped
             req.batched_with = max(ticket.admit_peers, peers - 1, 0)
             self.tokens_total += len(toks)
             if req.batched_with:
                 self.batched_invokes += 1
-        req.wall_s = time.perf_counter() - t0
+        req.t_collected = time.perf_counter()
+        req.wall_s = req.t_collected - t0
         self.invokes += 1
         self.invoke_seconds_total += req.wall_s
         return req

@@ -153,6 +153,10 @@ class ContainerSandbox:
         _, toks, peers = self._conn.recv()
         return toks, peers
 
+    def token_times(self, rid: int) -> None:
+        """Output-token stamps stay in the worker process: none here."""
+        return None
+
     def drain(self) -> Dict[int, List[int]]:
         return self._shutdown(drain=True)
 

@@ -254,8 +254,9 @@ class DecoderLM:
             if getattr(cache_len, "ndim", 0) == 1:
                 # per-slot lengths (continuous batching): scatter each row
                 bidx = jnp.arange(b)
-                ck = ck.at[bidx, cache_len].set(k[:, 0])
-                cv = cv.at[bidx, cache_len].set(v[:, 0])
+                with jax.named_scope("kv_write"):
+                    ck = ck.at[bidx, cache_len].set(k[:, 0])
+                    cv = cv.at[bidx, cache_len].set(v[:, 0])
                 o = L.decode_attention_jnp(q, ck, cv, cache_len + 1)
             elif self._use_sharded_decode():
                 from repro.models.distributed_attention import \
@@ -265,8 +266,9 @@ class DecoderLM:
                     model_axis=self.run.model_axis,
                     data_axes=self.run.data_axes)
             else:
-                ck = lax.dynamic_update_slice(ck, k, (0, cache_len, 0, 0))
-                cv = lax.dynamic_update_slice(cv, v, (0, cache_len, 0, 0))
+                with jax.named_scope("kv_write"):
+                    ck = lax.dynamic_update_slice(ck, k, (0, cache_len, 0, 0))
+                    cv = lax.dynamic_update_slice(cv, v, (0, cache_len, 0, 0))
                 o = L.decode_attention_jnp(q, ck, cv, cache_len + 1)
             new_kv = (ck, cv)
         o = jnp.einsum("bsh,hd->bsd", o.reshape(b, s, hq * hd), w["wo"])
@@ -292,10 +294,12 @@ class DecoderLM:
 
     def _block(self, w, x, pos, cache_kv=None, cache_len=None):
         dax, m = self.run.data_axes, self.run.model_axis
-        o, new_kv = self._attn(w, x, pos, cache_kv, cache_len)
+        with jax.named_scope("attn"):
+            o, new_kv = self._attn(w, x, pos, cache_kv, cache_len)
         x = x + o
         x = constrain(x, P(dax, None, None))
-        x = x + self._mlp(w, x)
+        with jax.named_scope("mlp"):
+            x = x + self._mlp(w, x)
         x = constrain(x, P(dax, None, None))
         return x, new_kv
 
@@ -368,18 +372,26 @@ class DecoderLM:
         return jnp.mean(lse - gold)
 
     def decode_step(self, params, cache, batch):
-        """One decode step: batch = {tokens (B,1), cache_len ()} -> logits."""
+        """One decode step: batch = {tokens (B,1), cache_len ()} -> logits.
+
+        Its parts carry ``jax.named_scope`` names (``embed``, ``attn`` with
+        ``kv_write`` inside, ``mlp``, ``final_norm``, ``logits``), so that a
+        device trace can place each operation of the step."""
         cfg = self.cfg
         tokens, cache_len = batch["tokens"], batch["cache_len"]
-        x = jnp.take(params["embed"], tokens, axis=0).astype(_dt(cfg))
+        with jax.named_scope("embed"):
+            x = jnp.take(params["embed"], tokens, axis=0).astype(_dt(cfg))
         pos = self._positions(tokens, offset=cache_len)
         x, new_cache = self._stack(params, x, pos, cache=cache,
                                    cache_len=cache_len)
-        x = L.rms_norm(x, params["final_norm"]) if cfg.norm == "rmsnorm" else \
-            L.layer_norm(x, params["final_norm"],
-                         jnp.zeros_like(params["final_norm"]))
+        with jax.named_scope("final_norm"):
+            x = L.rms_norm(x, params["final_norm"]) \
+                if cfg.norm == "rmsnorm" else \
+                L.layer_norm(x, params["final_norm"],
+                             jnp.zeros_like(params["final_norm"]))
         head = params.get("lm_head")
         if head is None:
             head = params["embed"].T
-        logits = jnp.einsum("bsd,dv->bsv", x, head)[:, -1]
+        with jax.named_scope("logits"):
+            logits = jnp.einsum("bsd,dv->bsv", x, head)[:, -1]
         return logits, new_cache

@@ -12,16 +12,23 @@ Two layers:
     lengths). This is the data-plane concurrency-throttling analogue: the
     sandbox advertises ``max_slots`` as its concurrency capacity to the
     Dirigent DP.
+
+Each batcher step is a ``jax.profiler.TraceAnnotation`` span, ``engine.step``,
+with children ``engine.prepare``, ``engine.dispatch``, ``engine.fetch`` and
+``engine.update``: free when no profiler runs, and on the device trace's
+clock when one does. Its stats (``slots``, ``active``, ``prompt``,
+``generated``) are also kept as cumulative counters on the batcher.
 """
 from __future__ import annotations
 
 import time
 from dataclasses import dataclass, field
-from typing import Callable, Dict, List, Optional
+from typing import Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ArchConfig, ShapeSpec
 from repro.models.api import RunConfig
@@ -64,7 +71,6 @@ class Replica:
         self.params = params
         self._decode = entry.decode
         self._prefill = entry.prefill
-        self.stats = {"requests": 0, "tokens": 0, "decode_steps": 0}
 
     def new_cache(self, batch: int):
         shape = ShapeSpec("serve", self.max_seq, batch, "decode")
@@ -82,7 +88,6 @@ class Replica:
             batch = {"tokens": jnp.array([[tok]], jnp.int32),
                      "cache_len": jnp.array(t, jnp.int32)}
             logits, cache = self._decode(self.params, cache, batch)
-            self.stats["decode_steps"] += 1
         pos = len(toks)
         for i in range(max_new_tokens):
             rng, sub = jax.random.split(rng)
@@ -91,10 +96,7 @@ class Replica:
             batch = {"tokens": jnp.array([[nxt]], jnp.int32),
                      "cache_len": jnp.array(pos, jnp.int32)}
             logits, cache = self._decode(self.params, cache, batch)
-            self.stats["decode_steps"] += 1
             pos += 1
-        self.stats["requests"] += 1
-        self.stats["tokens"] += len(out)
         return out
 
 
@@ -106,10 +108,20 @@ class Slot:
     generated: List[int] = field(default_factory=list)
     length: int = 0
     max_new: int = 0
+    t_first: float = 0.0             # perf_counter of the first output token
 
 
 class ContinuousBatcher:
-    """Decode-only continuous batching with per-slot cache lengths."""
+    """Decode-only continuous batching with per-slot cache lengths.
+
+    Counters, cumulative over steps: ``steps``; ``slot_steps``, active slots
+    summed over steps (the capacity is ``steps * max_slots``);
+    ``prompt_tokens``, slots that fed a prompt token and generated nothing
+    (every prompt token but the last: the step that feeds the last one
+    generates the first output token); ``generated_tokens``. So
+    ``prompt_tokens + generated_tokens == slot_steps``. ``token_times`` maps
+    a finished request id to the ``perf_counter`` stamps of its first and
+    last output token."""
 
     def __init__(self, replica: Replica, max_slots: int = 8):
         self.replica = replica
@@ -118,7 +130,11 @@ class ContinuousBatcher:
         self.cache = replica.new_cache(max_slots)
         self._next_id = 0
         self.finished: Dict[int, List[int]] = {}
+        self.token_times: Dict[int, Tuple[float, float]] = {}
         self.steps = 0
+        self.slot_steps = 0
+        self.prompt_tokens = 0
+        self.generated_tokens = 0
 
     @property
     def free_slots(self) -> int:
@@ -142,35 +158,54 @@ class ContinuousBatcher:
         """One lockstep decode over all slots; returns finished request ids."""
         if all(not s.active for s in self.slots):
             return []
-        tokens = np.zeros((self.max_slots, 1), np.int32)
-        lens = np.zeros((self.max_slots,), np.int32)
-        for i, s in enumerate(self.slots):
-            lens[i] = s.length
-            if not s.active:
-                continue
-            if s.pending:
-                tokens[i, 0] = s.pending.pop(0)
-            else:
-                tokens[i, 0] = (s.generated[-1] if s.generated else 0)
-        batch = {"tokens": jnp.asarray(tokens),
-                 "cache_len": jnp.asarray(lens)}
-        logits, self.cache = self.replica._decode(self.replica.params,
-                                                  self.cache, batch)
-        self.steps += 1
-        argmax = np.asarray(jnp.argmax(logits, axis=-1))
-        done: List[int] = []
-        for i, s in enumerate(self.slots):
-            if not s.active:
-                continue
-            s.length += 1
-            if s.pending:
-                continue               # still consuming the prompt
-            s.generated.append(int(argmax[i]))
-            if (len(s.generated) >= s.max_new
-                    or s.length >= self.replica.max_seq - 1):
-                self.finished[s.request_id] = s.generated
-                done.append(s.request_id)
-                s.active = False
+        with TraceAnnotation("engine.step") as span:
+            with TraceAnnotation("engine.prepare"):
+                tokens = np.zeros((self.max_slots, 1), np.int32)
+                lens = np.zeros((self.max_slots,), np.int32)
+                active = prompt = 0
+                for i, s in enumerate(self.slots):
+                    lens[i] = s.length
+                    if not s.active:
+                        continue
+                    active += 1
+                    if s.pending:
+                        tokens[i, 0] = s.pending.pop(0)
+                        if s.pending:
+                            prompt += 1        # no output from this step
+                    else:
+                        tokens[i, 0] = (s.generated[-1] if s.generated
+                                        else 0)
+                batch = {"tokens": jnp.asarray(tokens),
+                         "cache_len": jnp.asarray(lens)}
+            with TraceAnnotation("engine.dispatch"):
+                logits, self.cache = self.replica._decode(
+                    self.replica.params, self.cache, batch)
+            self.steps += 1
+            with TraceAnnotation("engine.fetch"):
+                argmax = np.asarray(jnp.argmax(logits, axis=-1))
+            now = time.perf_counter()
+            done: List[int] = []
+            with TraceAnnotation("engine.update"):
+                for i, s in enumerate(self.slots):
+                    if not s.active:
+                        continue
+                    s.length += 1
+                    if s.pending:
+                        continue               # still consuming the prompt
+                    if not s.generated:
+                        s.t_first = now
+                    s.generated.append(int(argmax[i]))
+                    if (len(s.generated) >= s.max_new
+                            or s.length >= self.replica.max_seq - 1):
+                        self.finished[s.request_id] = s.generated
+                        self.token_times[s.request_id] = (s.t_first, now)
+                        done.append(s.request_id)
+                        s.active = False
+            span.set_metadata(slots=self.max_slots, active=active,
+                              prompt=prompt, generated=active - prompt)
+        self.slot_steps += active
+        self.prompt_tokens += prompt
+        self.generated_tokens += active - prompt
         return done
 
     def abort(self) -> List[int]:
