@@ -201,19 +201,38 @@ def decode_attention_jnp(q, k_cache, v_cache, cache_len) -> jax.Array:
 
     q: (B, 1, Hq, D); caches: (B, Smax, Hkv, D); cache_len: int32 scalar.
     """
+    return decode_attention_positions_last(
+        q, jnp.moveaxis(k_cache, 1, 3), jnp.moveaxis(v_cache, 1, 3), cache_len)
+
+
+def decode_attention_positions_last(q, k_cache, v_cache,
+                                    cache_len) -> jax.Array:
+    """``decode_attention_jnp`` on caches (B, Hkv, D, Smax), positions last:
+    the layout of DecoderLM's decode cache, which the TPU's dots read as it
+    is stored. cache_len: int32 scalar or (B,)."""
     b, _, hq, d = q.shape
-    smax, hkv = k_cache.shape[1], k_cache.shape[2]
+    hkv, smax = k_cache.shape[1], k_cache.shape[3]
     g = hq // hkv
     scale = 1.0 / math.sqrt(d)
     qg = q.reshape(b, 1, hkv, g, d)
-    s = jnp.einsum("bqhgd,bkhd->bhgqk", qg.astype(jnp.float32),
+    s = jnp.einsum("bqhgd,bhdk->bhgqk", qg.astype(jnp.float32),
                    k_cache.astype(jnp.float32)) * scale
     pos = jnp.arange(smax)
     mask = pos[None, :] < jnp.reshape(cache_len, (-1, 1))      # (B, Smax)
     s = jnp.where(mask[:, None, None, None, :], s, NEG_INF)
     w = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bhgqk,bkhd->bqhgd", w, v_cache.astype(jnp.float32))
+    out = jnp.einsum("bhgqk,bhdk->bqhgd", w, v_cache.astype(jnp.float32))
     return out.reshape(b, 1, hq, d).astype(q.dtype)
+
+
+def with_row(cache, row, pos) -> jax.Array:
+    """``cache`` (..., B, Hkv, D, Smax), positions last, with ``row``
+    (..., B, Hkv, D) at position ``pos`` (scalar or (B,); a position past the
+    end writes nothing). A select: inside the layer scan XLA fuses it into
+    the attention's reads of the cache, so nothing is written there; over
+    the whole cache it is the one pass that makes the new cache."""
+    hot = jnp.arange(cache.shape[-1]) == jnp.reshape(pos, (-1, 1))
+    return jnp.where(hot[:, None, None, :], row[..., None], cache)
 
 
 # -- MLPs --------------------------------------------------------------------------

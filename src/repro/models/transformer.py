@@ -10,6 +10,12 @@ Design notes:
   * attention: blocked flash (jnp) for train/prefill, masked dense for
     single-token decode; GQA via KV-head repetition *after* cache update so
     the KV cache stays at n_kv_heads;
+  * the decode KV cache is ``[L, B, Hkv, D, Smax]``, positions last: the
+    TPU's own layout for it, which the attention's dots read with no
+    relayout. The layer scan reads each layer's cache in place, with the new
+    row selected in, and returns only the rows; one select after the scan
+    writes them all. The input cache is not donated, so that select is also
+    the one pass that copies it into the output.
   * MoE: expert-parallel sorted dispatch under shard_map (see moe.py).
 """
 from __future__ import annotations
@@ -184,8 +190,8 @@ class DecoderLM:
         hkv, hd = cfg.n_kv_heads, cfg.resolved_head_dim
         dt = _dt(cfg)
         return {
-            "k": jax.ShapeDtypeStruct((cfg.n_layers, b, smax, hkv, hd), dt),
-            "v": jax.ShapeDtypeStruct((cfg.n_layers, b, smax, hkv, hd), dt),
+            "k": jax.ShapeDtypeStruct((cfg.n_layers, b, hkv, hd, smax), dt),
+            "v": jax.ShapeDtypeStruct((cfg.n_layers, b, hkv, hd, smax), dt),
         }
 
     def cache_pspecs(self, shape: ShapeSpec) -> Dict[str, Any]:
@@ -193,9 +199,9 @@ class DecoderLM:
         cfg = self.cfg
         m = self.run.model_axis
         if cfg.n_kv_heads >= 16:
-            kv = P(None, dax, None, m, None)     # shard KV heads
+            kv = P(None, dax, m, None, None)     # shard KV heads
         else:
-            kv = P(None, dax, m, None, None)     # shard cache sequence
+            kv = P(None, dax, None, None, m)     # shard cache sequence
         return {"k": kv, "v": kv}
 
     def init_cache(self, shape: ShapeSpec, batch: Optional[int] = None):
@@ -203,7 +209,7 @@ class DecoderLM:
         b = batch or shape.global_batch
         hkv, hd = cfg.n_kv_heads, cfg.resolved_head_dim
         dt = _dt(cfg)
-        z = jnp.zeros((cfg.n_layers, b, shape.seq_len, hkv, hd), dt)
+        z = jnp.zeros((cfg.n_layers, b, hkv, hd, shape.seq_len), dt)
         return {"k": z, "v": z}
 
     # ------------------------------------------------------------------ blocks
@@ -224,7 +230,10 @@ class DecoderLM:
         return L.apply_rope(x, pos, cfg.rope_theta)
 
     def _attn(self, w, x, pos, cache_kv=None, cache_len=None):
-        """Returns (attn_out, new_kv) where new_kv is (k, v) for this layer."""
+        """Returns (attn_out, (k, v)): this layer's new keys and values,
+        (B, S, Hkv, D). In decode ``cache_kv`` is this layer's (k, v) cache,
+        (B, Hkv, D, Smax), read in place; the new row, (B, Hkv, D), is
+        attended to where ``_stack`` writes it after the layer scan."""
         cfg, run = self.cfg, self.run
         b, s, d = x.shape
         hq, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
@@ -248,31 +257,25 @@ class DecoderLM:
                                           q_chunk=run.q_chunk,
                                           kv_chunk=run.kv_chunk,
                                           unroll=run.attn_unroll)
-            new_kv = (k, v)
         else:
             ck, cv = cache_kv
-            if getattr(cache_len, "ndim", 0) == 1:
-                # per-slot lengths (continuous batching): scatter each row
-                bidx = jnp.arange(b)
-                with jax.named_scope("kv_write"):
-                    ck = ck.at[bidx, cache_len].set(k[:, 0])
-                    cv = cv.at[bidx, cache_len].set(v[:, 0])
-                o = L.decode_attention_jnp(q, ck, cv, cache_len + 1)
-            elif self._use_sharded_decode():
+            if self._use_sharded_decode() and \
+                    getattr(cache_len, "ndim", 0) == 0:
                 from repro.models.distributed_attention import \
                     decode_attention_seq_sharded
-                o, ck, cv = decode_attention_seq_sharded(
+                o = decode_attention_seq_sharded(
                     q, ck, cv, k, v, cache_len,
                     model_axis=self.run.model_axis,
                     data_axes=self.run.data_axes)
             else:
                 with jax.named_scope("kv_write"):
-                    ck = lax.dynamic_update_slice(ck, k, (0, cache_len, 0, 0))
-                    cv = lax.dynamic_update_slice(cv, v, (0, cache_len, 0, 0))
-                o = L.decode_attention_jnp(q, ck, cv, cache_len + 1)
-            new_kv = (ck, cv)
+                    ck = L.with_row(ck, k[:, 0], cache_len)
+                    cv = L.with_row(cv, v[:, 0], cache_len)
+                o = L.decode_attention_positions_last(q, ck, cv,
+                                                     cache_len + 1)
+            k, v = k[:, 0], v[:, 0]
         o = jnp.einsum("bsh,hd->bsd", o.reshape(b, s, hq * hd), w["wo"])
-        return o, new_kv
+        return o, (k, v)
 
     def _use_sharded_decode(self) -> bool:
         """HC2: explicit distributed flash-decode when the cache is
@@ -304,46 +307,50 @@ class DecoderLM:
         return x, new_kv
 
     def _stack(self, params, x, pos, cache=None, cache_len=None):
-        """Run the layer stack; returns (x, new_cache or None)."""
-        layers = params["layers"]
-        block = self._block
-        if self.run.remat and cache is None:   # no backward pass in decode
-            if self.run.remat_policy == "dots":
-                block = jax.checkpoint(
-                    block, policy=jax.checkpoint_policies
-                    .dots_with_no_batch_dims_saveable)
-            else:
-                block = jax.checkpoint(block)
+        """Run the layer stack; returns (x, new_cache or None).
 
-        def body(carry, wl):
-            x = carry
-            if cache is None:
-                x, _ = block(wl, x, pos)
-                return x, None
-            w, (ck, cv) = wl
-            x, (nk, nv) = block(w, x, pos, (ck, cv), cache_len)
-            return x, (nk, nv)
+        In decode each layer reads its cache in place and gives back only its
+        new (k, v) rows; one select over the whole cache writes them after
+        the scan. Passed through the scan's ``xs`` and ``ys``, the cache
+        would be copied out and back a layer at a time on every step;
+        written into the scan's carry layer by layer, XLA relays the carried
+        cache positions-major for the write, and each layer back for the
+        dots."""
+        layers = params["layers"]
+        if cache is None:
+            block = self._block
+            if self.run.remat:
+                if self.run.remat_policy == "dots":
+                    block = jax.checkpoint(
+                        block, policy=jax.checkpoint_policies
+                        .dots_with_no_batch_dims_saveable)
+                else:
+                    block = jax.checkpoint(block)
+
+            def body(x, w):
+                return block(w, x, pos)[0], None
+            carry = x
+        else:
+            def body(carry, w):
+                i, x = carry
+                kv = (cache["k"][i], cache["v"][i])
+                x, rows = self._block(w, x, pos, kv, cache_len)
+                return (i + 1, x), rows
+            carry = (0, x)
 
         if self.run.layer_mode == "scan":
-            if cache is None:
-                x, _ = lax.scan(body, x, layers)
-                return x, None
-            x, (nk, nv) = lax.scan(body, x, (layers, (cache["k"], cache["v"])))
-            return x, {"k": nk, "v": nv}
-        # unrolled
-        nks, nvs = [], []
-        for i in range(self.cfg.n_layers):
-            wl = jax.tree.map(lambda a: a[i], layers)
-            if cache is None:
-                x, _ = block(wl, x, pos)
-            else:
-                x, (nk, nv) = block(wl, x, pos,
-                                    (cache["k"][i], cache["v"][i]), cache_len)
-                nks.append(nk)
-                nvs.append(nv)
+            carry, rows = lax.scan(body, carry, layers)
+        else:
+            rows = []
+            for i in range(self.cfg.n_layers):
+                carry, r = body(carry, jax.tree.map(lambda a: a[i], layers))
+                rows.append(r)
+            rows = jax.tree.map(lambda *r: jnp.stack(r), *rows)
         if cache is None:
-            return x, None
-        return x, {"k": jnp.stack(nks), "v": jnp.stack(nvs)}
+            return carry, None
+        with jax.named_scope("kv_write"):
+            return carry[1], {"k": L.with_row(cache["k"], rows[0], cache_len),
+                              "v": L.with_row(cache["v"], rows[1], cache_len)}
 
     # ------------------------------------------------------------------ steps
     def forward(self, params, batch) -> jax.Array:
@@ -375,8 +382,9 @@ class DecoderLM:
         """One decode step: batch = {tokens (B,1), cache_len ()} -> logits.
 
         Its parts carry ``jax.named_scope`` names (``embed``, ``attn`` with
-        ``kv_write`` inside, ``mlp``, ``final_norm``, ``logits``), so that a
-        device trace can place each operation of the step."""
+        ``kv_write`` inside, ``mlp``, ``final_norm``, ``logits``, and the
+        ``kv_write`` after the layer scan that writes the new rows), so that
+        a device trace can place each operation of the step."""
         cfg = self.cfg
         tokens, cache_len = batch["tokens"], batch["cache_len"]
         with jax.named_scope("embed"):

@@ -80,6 +80,35 @@ def test_served_decode_step_full_smollm_width(one_chip):
             + mem.temp_size_in_bytes) < V5E_HBM_BYTES
 
 
+def test_served_decode_step_copies_no_layer_of_cache(one_chip):
+    """At the benchmark's 16 slots x 2048 positions, the compiled decode step
+    neither copies nor transposes an array the size of one layer's KV cache:
+    the layer scan reads the cache as it is stored, and one select writes
+    the new rows. (Passed through the scan's xs and ys, every layer's cache
+    was copied out, relaid out for the dots and stacked back.)"""
+    import math
+    import re
+
+    cfg = get_config("smollm-360m")
+    slots, max_seq = 16, 2048
+    entry = ExecutableCache().get(cfg, SERVE_RUN_CFG)
+    model = entry.model
+    params = _on(model.param_specs(), one_chip)
+    cache = _on(model.cache_specs(ShapeSpec("serve", max_seq, slots,
+                                            "decode")), one_chip)
+    batch = {"tokens": _spec((slots, 1), jnp.int32, one_chip),
+             "cache_len": _spec((slots,), jnp.int32, one_chip)}
+    text = entry.decode.lower(params, cache, batch).compile().as_text()
+    layer = math.prod(cache["k"].shape[1:])
+    moved = []
+    for shape, op in re.findall(r"= \w+\[([\d,]+)\]\S* (copy|transpose)\(",
+                                text):
+        dims = [int(d) for d in shape.split(",")]
+        if max_seq in dims and math.prod(dims) >= layer:
+            moved.append((op, dims))
+    assert not moved, moved
+
+
 def test_decode_attention_pallas_smollm_heads(one_chip):
     q = _spec((8, 1, 15, 64), jnp.bfloat16, one_chip)
     kv = _spec((8, 2048, 5, 64), jnp.bfloat16, one_chip)
