@@ -2,9 +2,11 @@
 correctness check, and the metrics.
 
 Everything a cell needs is found by name from ``BENCHMARK.json``: the
-configuration file, ``bench/traffic/<mix>.json`` and the generator kind it
-names in ``bench/traffic/kinds/<kind>.py``, and one reader per per-layer
-metric in ``bench/metrics/<metric>.py``.
+configuration file and the payload it names in ``bench/payloads/<payload>.py``
+(the program mapping, the reference and the step counts of its
+architecture), ``bench/traffic/<mix>.json`` and the generator kind it names
+in ``bench/traffic/kinds/<kind>.py``, and one reader per per-layer metric in
+``bench/metrics/<metric>.py``.
 
 The window drives ``Cluster.invoke(fn, request=LiveRequest(...))``. The
 harness owns a wall clock that starts when warm-up ends. An arrival due at
@@ -67,6 +69,7 @@ class Cell:
     root: Path
     workload: Dict
     config: Dict
+    payload: object                  # the module bench/payloads/<payload>.py
     traffic: Dict
     end_to_end: List[Dict]
     per_layer: List[Dict]
@@ -84,7 +87,8 @@ class Cell:
         config = json.loads((root / cfg_entry["file"]).read_text())
         traffic = json.loads(
             (root / "bench" / "traffic" / f"{wl['traffic']}.json").read_text())
-        return cls(root=root, workload=wl, config=config, traffic=traffic,
+        return cls(root=root, workload=wl, config=config,
+                   payload=load_payload(root, config), traffic=traffic,
                    end_to_end=[m for m in spec["end_to_end"]
                                if _applies(m, name)],
                    per_layer=[m for m in spec["per_layer"]
@@ -136,6 +140,7 @@ class Record:
 
     seconds: float                   # the measured window's length
     arch: Dict = field(default_factory=dict)
+    payload: object = None           # the configuration's payload module
     peaks: Optional[Dict] = None
     reqs: List[Req] = field(default_factory=list)
     steps: List[Step] = field(default_factory=list)
@@ -151,6 +156,15 @@ class Record:
 
     def window_steps(self) -> List[Step]:
         return [s for s in self.steps if self.in_window(s.t0)]
+
+    def traced_steps(self) -> List[Step]:
+        """The steps inside the traced part of the window."""
+        return [s for s in self.steps
+                if any(a <= s.t0 and s.t1 <= b
+                       for a, b in self.spans.get("trace", ()))]
+
+    def traced_seconds(self) -> float:
+        return sum(b - a for a, b in self.spans.get("trace", ()))
 
 
 class Harness:
@@ -168,8 +182,12 @@ class Harness:
         self.jax = jax
         self.cell, self.seed = cell, seed
         cfg = cell.config
-        self.arch = arch_of(cfg)
-        self.cfg = payload_config(get_config(cfg["arch"]), cfg)
+        self.arch = arch_of(cfg, cell.payload)
+        try:
+            self.cfg = cell.payload.program_config(get_config(cfg["arch"]),
+                                                   cfg)
+        except ValueError as e:
+            raise BenchError(f"payload {cfg['payload']!r}: {e}") from None
         cl = cfg["cluster"]
         spec = LiveFunctionSpec(cfg=self.cfg, max_seq=cl["max_seq"],
                                 max_slots=cl["slots"])
@@ -189,7 +207,8 @@ class Harness:
                 port=9000, scaling=ScalingConfig(**f["scaling"])))
         self.trace_dir = trace_dir
         self.t0 = time.perf_counter()    # re-based when the window opens
-        self.rec = Record(seconds=0.0, arch=self.arch)
+        self.rec = Record(seconds=0.0, arch=self.arch,
+                          payload=cell.payload)
         self.by_inv: Dict[int, Req] = {}
         self.by_request: Dict[int, int] = {}      # id(LiveRequest) -> sandbox
         self.source = None
@@ -384,31 +403,24 @@ class Harness:
 
 # -- configuration -----------------------------------------------------------------
 
-ARCH_KEYS = ("hidden_size", "intermediate_size", "num_attention_heads",
-             "num_key_value_heads", "head_dim", "num_hidden_layers",
-             "vocab_size", "rope_theta", "rms_norm_eps")
+def load_payload(root: Path, cfg: Dict):
+    """The payload module that the configuration file names, loaded from
+    ``bench/payloads/`` of the checkout at ``root``."""
+    name = cfg.get("payload")
+    if name is None:
+        raise BenchError(f"configuration {cfg.get('name')!r} names no "
+                         f"payload (the key \"payload\")")
+    mod = _load_module(root / "bench" / "payloads" / f"{name}.py",
+                       f"bench_payload_{name}")
+    if "vocab_size" not in mod.KEYS:
+        raise BenchError(f"payload {name!r} does not read vocab_size")
+    return mod
 
 
-def arch_of(cfg: Dict) -> Dict:
-    """The payload's sizes and served dtype, as the reference reads them."""
-    return {k: cfg[k] for k in ARCH_KEYS + ("dtype",)}
-
-
-def payload_config(base, cfg: Dict):
-    """The program's ``ArchConfig`` for the configuration file: the
-    registered architecture with every size the file states."""
-    import dataclasses
-
-    if cfg.get("hidden_act", "silu") != "silu" or not cfg.get(
-            "tie_word_embeddings", True):
-        raise BenchError("the reference covers SwiGLU with tied embeddings")
-    return dataclasses.replace(
-        base, n_layers=cfg["num_hidden_layers"], d_model=cfg["hidden_size"],
-        n_heads=cfg["num_attention_heads"],
-        n_kv_heads=cfg["num_key_value_heads"], d_ff=cfg["intermediate_size"],
-        vocab=cfg["vocab_size"], head_dim=cfg["head_dim"],
-        rope_theta=float(cfg["rope_theta"]), tie_embeddings=True,
-        dtype=cfg["dtype"])
+def arch_of(cfg: Dict, payload) -> Dict:
+    """The payload's sizes and the served dtype, as the reference and the
+    step counts read them."""
+    return dict({k: cfg[k] for k in payload.KEYS}, dtype=cfg["dtype"])
 
 
 # -- the run ---------------------------------------------------------------------------
@@ -549,8 +561,8 @@ def run(root: Path, workload: str, seed: int, seconds: float, trace: bool,
                                       "unit": m["unit"]}
 
     t_ref = time.perf_counter()
-    ref = reference.check(samples, rec.arch, cell.config["dtype"],
-                          control=control) \
+    ref = reference.check(samples, cell.payload, rec.arch,
+                          cell.config["dtype"], control=control) \
         if samples else {"logit_gap": None, "tokens_checked": 0}
     print(f"reference: {len(samples)} requests, {ref['tokens_checked']} "
           f"served tokens, {time.perf_counter() - t_ref:.3f} s", file=sys.stderr)

@@ -1,12 +1,14 @@
 """Model FLOP utilization of the whole serving path: the FLOPs of every
-token fed through a decode step in the window (prompt and generated, each
-attending to its context), over the window times the chip's bf16 peak."""
-from bench import flops
+token fed through a decode step in the traced part of the window (prompt and
+generated, each attending to its context), over that part's length times the
+chip's bf16 peak. Stopping the profiler holds the host with no step running,
+so the rest of the window is left out of both."""
 
 
 def read(rec):
-    steps = rec.window_steps()
+    steps = rec.traced_steps()
     if not steps or rec.peaks is None:
         return None
-    total = sum(flops.step_flops(rec.arch, s.lens) for s in steps)
-    return 100.0 * total / (rec.seconds * rec.peaks["bf16_flops_per_s"])
+    total = sum(rec.payload.step_flops(rec.arch, s.lens) for s in steps)
+    return 100.0 * total / (rec.traced_seconds()
+                            * rec.peaks["bf16_flops_per_s"])

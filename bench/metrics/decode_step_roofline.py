@@ -1,9 +1,8 @@
 """Share of the HBM roofline that the served decode step reaches: the bytes
-the step needs (``bench.flops.step_bytes``, from each step's active cache
-lengths) over the device time of the decode executable in the trace, against
-the chip's peak bandwidth. The FLOP bound is far lower at these shapes, and
+the step needs (the payload's ``step_bytes``, from each step's active cache
+lengths) over the device time of the decode executable in the trace,
+against the chip's peak bandwidth. The FLOP bound is far lower at these shapes, and
 the larger of the two bounds is the one taken."""
-from bench import flops
 
 MODULE = "jit_decode_step"
 
@@ -17,18 +16,16 @@ def read(rec):
         # renamed, and the metric must not go silent
         raise RuntimeError(f"no {MODULE} in the trace's executables: "
                            f"{sorted(rec.trace['modules'])}")
-    steps = [s for s in rec.steps
-             if any(a <= s.t0 and s.t1 <= b
-                    for a, b in rec.spans.get("trace", ()))]
+    steps = rec.traced_steps()
     if not mod or not mod[1] or not steps or rec.peaks is None:
         return None
     n, device_s = mod
     dtype = rec.arch.get("dtype", "bfloat16")
     need_s = 0.0
     for s in steps:
-        need_s += max(flops.step_bytes(rec.arch, dtype, s.lens)
+        need_s += max(rec.payload.step_bytes(rec.arch, dtype, s.lens)
                       / rec.peaks["hbm_bytes_per_s"],
-                      flops.step_flops(rec.arch, s.lens)
+                      rec.payload.step_flops(rec.arch, s.lens)
                       / rec.peaks["bf16_flops_per_s"])
     # the trace's count of decode runs and the harness's count of steps
     # cover the same window; scale by the trace's count

@@ -3,6 +3,7 @@ harness as on the chip; the command itself refuses to run without a TPU."""
 import json
 import subprocess
 import sys
+from pathlib import Path
 
 import pytest
 
@@ -91,3 +92,119 @@ def test_new_mix_is_new_files_only(tree, rehearse):
     out = rehearse("llm.throwaway")
     assert out["correct"]
     assert set(out["metrics"]) == {"setup_s", "invoke_p50_ms"}
+
+
+@pytest.mark.parametrize("payload", [None, "nowhere"],
+                         ids=["no_key", "no_module"])
+def test_configuration_without_its_payload_is_refused(tree, payload):
+    from bench.harness import BenchError, Cell
+    from bench.tests.tiny import _edit
+
+    def name(c):
+        c.pop("payload")
+        return c if payload is None else dict(c, payload=payload)
+    _edit(tree / "bench" / "configs" / "llm-smollm-360m.json", name)
+    with pytest.raises(BenchError, match="llm-smollm-360m" if payload is None
+                       else "nowhere.py"):
+        Cell.load(tree, "llm.saturate")
+
+
+# A payload of another arithmetic than llama's: the same decoder, with an
+# untied output head made as the program makes ``lm_head``. BROKEN forgets
+# that head and reads the logits through the embedding, as llama does.
+THROWAWAY = '''
+import dataclasses
+import math
+from functools import partial
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from bench.payloads import llama
+from bench.reference import mm
+
+BROKEN = {broken}
+KEYS, TINY, WIDTHS = llama.KEYS, llama.TINY, llama.WIDTHS
+step_bytes, step_flops = llama.step_bytes, llama.step_flops
+# widest logit gap of the untied head at TINY on the CPU, over twelve seeds:
+# served bf16 0 to 0.0324 (its logits are some six times the tied head's,
+# and so are its gaps), BROKEN 0.680 to 1.003
+TINY_LIMIT = 0.1
+
+
+def program_config(base, cfg):
+    tied = llama.program_config(base, dict(cfg, tie_word_embeddings=True))
+    return dataclasses.replace(tied, tie_embeddings=False)
+
+
+def init_params(seed, arch, dtype):
+    d, v = arch["hidden_size"], arch["vocab_size"]
+    key = jax.random.split(jax.random.PRNGKey(seed), 8)[3]
+    head = (jax.random.normal(key, (d, v)) * (1.0 / math.sqrt(d)))
+    return dict(llama.init_params(seed, arch, dtype),
+                lm_head=head.astype(dtype).astype(jnp.float32))
+
+
+@partial(jax.jit, static_argnums=(2, 3))
+def _forward(params, tokens, arch_items, fp8):
+    x = llama.decoder(params, tokens, dict(arch_items), fp8)
+    head = params["embed"].T if BROKEN else params["lm_head"]
+    return mm("sd,dv->sv", x, head, fp8)
+
+
+def forward(params, tokens, arch, fp8=False):
+    n = len(tokens)
+    padded = np.zeros((-(-n // 128) * 128,), np.int32)
+    padded[:n] = tokens
+    out = _forward(params, jnp.asarray(padded), tuple(sorted(arch.items())),
+                   fp8)
+    return np.asarray(out[:n], np.float32)
+'''
+
+
+
+def _files(root):
+    return {p.relative_to(root): p.read_bytes() for p in root.rglob("*")
+            if p.is_file() and "__pycache__" not in p.parts}
+
+
+@pytest.mark.parametrize("broken", [False, True], ids=["sound", "broken"])
+def test_new_configuration_is_new_files_only(tree, rehearse, broken):
+    """A configuration of an architecture the benchmark has never seen,
+    added as its payload file, its configuration file naming it, and
+    entries in BENCHMARK.json: no other file changes. With the payload's
+    logits broken, the same cell is not correct."""
+    from bench.tests.tiny import tiny_config
+
+    before = _files(tree)
+    (tree / "bench" / "payloads" / "throwaway.py").write_text(
+        THROWAWAY.format(broken=broken))
+    cfg = json.loads((tree / "bench" / "configs"
+                      / "llm-smollm-360m.json").read_text())
+    cfg.update(name="llm-throwaway", payload="throwaway",
+               tie_word_embeddings=False)
+    path = tree / "bench" / "configs" / "llm-throwaway.json"
+    path.write_text(json.dumps(cfg))
+    tiny_config(tree, path)
+    assert json.loads(path.read_text())["reference"]["limit"] == 0.1
+    spec = json.loads((tree / "BENCHMARK.json").read_text())
+    spec["configs"].append({"name": "llm-throwaway",
+                            "file": "bench/configs/llm-throwaway.json",
+                            "source": "test", "reduced": [], "why": "test"})
+    spec["workloads"].append({"name": "throwaway.saturate",
+                              "config": "llm-throwaway",
+                              "traffic": "closed_48", "chips": 1,
+                              "why": "test"})
+    for m in spec["end_to_end"]:
+        if m["name"] == "tokens_per_s":
+            m["workloads"].append("throwaway.saturate")
+    (tree / "BENCHMARK.json").write_text(json.dumps(spec))
+    after = _files(tree)
+    assert {k for k in before if before[k] != after[k]} == \
+        {Path("BENCHMARK.json")}
+
+    out = rehearse("throwaway.saturate")
+    assert out["correct"] is not broken, out["checks"]
+    assert out["attempted"] > 0 and out["failed"] == 0
+    assert set(out["metrics"]) == {"setup_s", "tokens_per_s"}

@@ -1,30 +1,29 @@
 """The control, put in the program's place, fails the committed limit at the
 cells' widths: the tokens that an fp8 pass of the reference puts first go
 through the same checks as the served tokens and ``correct`` comes out
-false, while the program's own tokens pass. Depth is cut to two layers so
-that a CPU test can hold it; the chip readings at full depth are in
-PERF.md."""
+false, while the program's own tokens pass. The widths are the
+configuration's own (its payload's ``WIDTHS``); the rest stays at the
+payload's ``TINY`` sizes, such as a depth that a CPU test can hold. The chip
+readings at full size are in PERF.md."""
 import json
 
 import pytest
 
+from bench.harness import load_payload
 from bench.tests.conftest import REPO
 from bench.tests.tiny import _edit
 
 CONFIG = "bench/configs/llm-smollm-360m.json"
-WIDTHS = ("hidden_size", "intermediate_size", "num_attention_heads",
-          "num_key_value_heads", "head_dim", "vocab_size", "rms_norm_eps")
 
 
 @pytest.fixture
 def wide(tree):
-    """The tiny tree with the configuration's own widths, epsilon and
-    limit, at two layers."""
+    """The tiny tree with the configuration's own widths and limit."""
     src = json.loads((REPO / CONFIG).read_text())
+    widths = load_payload(tree, src).WIDTHS
 
     def widen(c):
-        c.update({k: src[k] for k in WIDTHS})
-        c["num_hidden_layers"] = 2
+        c.update({k: src[k] for k in widths})
         # the closed loop's first requests, all that a CPU serves in time
         c["reference"] = {"tokens": 16, "limit": src["reference"]["limit"]}
         return c

@@ -22,7 +22,12 @@ def _alter_tokens(monkeypatch):
 
 
 def _stale_cache(monkeypatch):
-    """The decode step hands back its cache unchanged."""
+    """The decode step hands back its cache unchanged: a copy of the input
+    cache taken before the call, since a call that donates its input cache
+    deletes that buffer."""
+    import jax
+    import jax.numpy as jnp
+
     from repro.serving.exec_cache import ExecutableCache
 
     real = ExecutableCache.get
@@ -31,10 +36,38 @@ def _stale_cache(monkeypatch):
         entry = real(self, *a, **kw)
         if not getattr(entry, "_broken", False):
             decode = entry.decode
+
+            def stale(p, c, b):
+                before = jax.tree.map(jnp.copy, c)
+                return decode(p, c, b)[0], before
             # through monkeypatch, so the shared cache is mended afterwards
-            monkeypatch.setattr(entry, "decode",
-                                lambda p, c, b: (decode(p, c, b)[0], c))
+            monkeypatch.setattr(entry, "decode", stale)
             monkeypatch.setattr(entry, "_broken", True, raising=False)
+        return entry
+    monkeypatch.setattr(ExecutableCache, "get", get)
+
+
+def _donate_cache(monkeypatch):
+    """The decode step donates its input cache, as the program may: the
+    call deletes the buffers it was given. (A new cache's keys and values
+    are one buffer in the program, which cannot be donated twice: each
+    gets its own here.)"""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.serving.exec_cache import ExecutableCache
+
+    real = ExecutableCache.get
+
+    def get(self, *a, **kw):
+        entry = real(self, *a, **kw)
+        if not getattr(entry, "_donating", False):
+            model, init_cache = entry.model, entry.model.init_cache
+            monkeypatch.setattr(model, "init_cache", lambda *a, **kw: (
+                jax.tree.map(jnp.copy, init_cache(*a, **kw))))
+            monkeypatch.setattr(entry, "decode", jax.jit(
+                model.decode_step, donate_argnums=1))
+            monkeypatch.setattr(entry, "_donating", True, raising=False)
         return entry
     monkeypatch.setattr(ExecutableCache, "get", get)
 
@@ -82,3 +115,18 @@ def test_fault_makes_run_incorrect(tree, rehearse, monkeypatch, fault,
         fault(monkeypatch)
     out = rehearse(workload)
     assert out["correct"] is False, out["checks"]
+
+
+@pytest.mark.parametrize("fault", [None, _stale_cache],
+                         ids=["sound", "stale_cache"])
+def test_stale_cache_fault_holds_with_a_donated_cache(tree, rehearse,
+                                                      monkeypatch, fault):
+    """With the cache donated the program still serves correctly, and the
+    stale-cache fault, which hands back a copy of the input cache, still
+    makes the run not correct (handing back the input itself would hand
+    back a deleted buffer)."""
+    _donate_cache(monkeypatch)
+    if fault is not None:
+        fault(monkeypatch)
+    out = rehearse("llm.saturate")
+    assert out["correct"] is (fault is None), out["checks"]

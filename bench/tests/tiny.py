@@ -1,8 +1,9 @@
 """A copy of the benchmark at a tiny size, for rehearsals on the CPU.
 
 ``make_tree(tmp)`` copies ``BENCHMARK.json`` and ``bench/`` into ``tmp``
-and rewrites each configuration and mix there to a two-layer payload and
-short requests, so that every cell runs in seconds through the same harness.
+and rewrites each configuration there to its payload's tiny sizes and
+limit (the payload file's ``TINY`` and ``TINY_LIMIT``) and each mix to short
+requests, so that every cell runs in seconds through the same harness.
 The CPU gets a made-up entry in the copied peaks table: numbers read against
 it are rehearsals, never device metrics.
 """
@@ -14,21 +15,29 @@ from pathlib import Path
 
 REPO = Path(__file__).resolve().parents[2]
 
-TINY_ARCH = {"hidden_size": 64, "intermediate_size": 128,
-             "num_attention_heads": 4, "num_key_value_heads": 2,
-             "head_dim": 16, "num_hidden_layers": 2, "vocab_size": 512}
 TINY_LENGTHS = {
     "prompt": {"dist": "lognormal", "median": 8, "sigma": 0.6,
                "min": 2, "max": 24},
     "output": {"dist": "uniform", "min": 2, "max": 8}}
-# widest logit gap at this size on the CPU, over eight seeds of 48 served
-# tokens: served bf16 0 to 0.0036, the fp8 control 0.020 to 0.111, every
-# token changed 0.73 to 0.93
-TINY_LIMIT = 0.01
 
 
 def _edit(path: Path, fn) -> None:
     path.write_text(json.dumps(fn(json.loads(path.read_text())), indent=1))
+
+
+def tiny_config(root: Path, path: Path) -> None:
+    """Rewrite the configuration file at ``path`` in the tree at ``root`` to
+    its payload's ``TINY`` sizes and ``TINY_LIMIT``, and a small cluster."""
+    from bench.harness import load_payload
+
+    def tiny(c):
+        payload = load_payload(root, c)
+        c.update(payload.TINY)
+        c["cluster"].update(slots=4, max_seq=64)
+        c["drain_cap_s"] = 20
+        c["reference"] = {"tokens": 32, "limit": payload.TINY_LIMIT}
+        return c
+    _edit(path, tiny)
 
 
 def make_tree(tmp: Path) -> Path:
@@ -41,15 +50,8 @@ def make_tree(tmp: Path) -> Path:
                     dirs_exist_ok=True)
     bench = root / "bench"
 
-    def tiny_config(c):
-        c.update(TINY_ARCH)
-        c["cluster"].update(slots=4, max_seq=64)
-        c["drain_cap_s"] = 20
-        c["reference"] = {"tokens": 32, "limit": TINY_LIMIT}
-        return c
-
     for path in (bench / "configs").glob("*.json"):
-        _edit(path, tiny_config)
+        tiny_config(root, path)
 
     def tiny_mix(t):
         p = t["params"]
