@@ -73,6 +73,7 @@ class Cell:
     traffic: Dict
     end_to_end: List[Dict]
     per_layer: List[Dict]
+    quantile: float                  # of the gaps that logit_gap reads
 
     @classmethod
     def load(cls, root: Path, name: str) -> "Cell":
@@ -85,6 +86,12 @@ class Cell:
             raise BenchError(f"no workload {name!r} in {spec_path}")
         cfg_entry = {c["name"]: c for c in spec["configs"]}[wl["config"]]
         config = json.loads((root / cfg_entry["file"]).read_text())
+        quantile = config["reference"].get("quantile", 1.0)
+        if (isinstance(quantile, bool)
+                or not isinstance(quantile, (int, float))
+                or not 0 < quantile <= 1):
+            raise BenchError(f"{cfg_entry['file']}: reference.quantile "
+                             f"{quantile!r} is not a number in (0, 1]")
         traffic = json.loads(
             (root / "bench" / "traffic" / f"{wl['traffic']}.json").read_text())
         return cls(root=root, workload=wl, config=config,
@@ -92,7 +99,8 @@ class Cell:
                    end_to_end=[m for m in spec["end_to_end"]
                                if _applies(m, name)],
                    per_layer=[m for m in spec["per_layer"]
-                              if _applies(m, name)])
+                              if _applies(m, name)],
+                   quantile=float(quantile))
 
     def generator(self, functions: List[str], seconds: float):
         """The mix's source of arrivals. Its schedule (sizes and times) is
@@ -562,10 +570,18 @@ def run(root: Path, workload: str, seed: int, seconds: float, trace: bool,
 
     t_ref = time.perf_counter()
     ref = reference.check(samples, cell.payload, rec.arch,
-                          cell.config["dtype"], control=control) \
+                          cell.config["dtype"], control=control,
+                          quantile=cell.quantile) \
         if samples else {"logit_gap": None, "tokens_checked": 0}
     print(f"reference: {len(samples)} requests, {ref['tokens_checked']} "
           f"served tokens, {time.perf_counter() - t_ref:.3f} s", file=sys.stderr)
+    for who in ("program", "control"):
+        if who in ref:
+            d = ref[who]
+            qs = ", ".join(f"q{q} {v!r}" for q, v in d["quantiles"].items())
+            print(f"gaps of the {who}: judged at quantile {cell.quantile!r}; "
+                  f"widest {d['widest']!r}, share_off {d['share_off']!r}, "
+                  f"{qs}", file=sys.stderr)
     if control:
         print(f"control: the program's own logit_gap {ref['logit_gap']}, "
               f"the control's {ref.get('control_logit_gap')}",

@@ -56,30 +56,60 @@ def control_gaps(ref_logits: np.ndarray, low_logits: np.ndarray,
     return token_gaps(ref_logits, prompt_len, list(pick))
 
 
+QUANTILES = (0.5, 0.9, 0.99, 1.0)
+
+
+def at(gaps: np.ndarray, q: float) -> float:
+    """The q-quantile of a pool of gaps, an observed gap (numpy's
+    ``method="higher"``): at 1.0 the widest."""
+    return float(np.quantile(gaps, q, method="higher"))
+
+
+def spread(gaps: np.ndarray) -> Dict:
+    """How a pool of served tokens' gaps is spread, for the log: the widest,
+    the share of tokens off the reference's best (gap above 0) and the
+    ``QUANTILES``."""
+    return {"widest": float(gaps.max()),
+            "share_off": float(np.mean(gaps > 0)),
+            "quantiles": {q: at(gaps, q) for q in QUANTILES}}
+
+
 def check(samples: List[Dict], payload, arch: Dict, dtype: str,
-          control: bool = False) -> Dict[str, float]:
+          control: bool = False, quantile: float = 1.0) -> Dict:
     """Run the reference over each sample (``seed``, ``prompt``, ``tokens``:
-    the served tokens) and return the widest gap of a served token, with
-    the control's widest gap too when ``control`` is set. Samples are
-    grouped by replica seed, so each replica's weights are made once. The
-    weights and logits are those of ``payload``, the configuration's
-    payload module."""
-    widest, widest_ctl, n_tok = 0.0, 0.0, 0
+    the served tokens) and pool the gap of every served token. Returns the
+    ``quantile`` of that pool as ``logit_gap`` (``at``: at the default 1.0,
+    the widest gap), ``tokens_checked``, and the pool's ``spread`` as
+    ``program``; with ``control`` set, the same of the control's pool as
+    ``control_logit_gap`` and ``control``.
+
+    A quantile q under a limit L holds exactly when at most
+    floor((1 - q)(n - 1)) of the n pooled tokens lie more than L below the
+    reference's best logit: about a share 1 - q of them. A configuration
+    that names q < 1 states, in its ``reference.witnesses``, its program's
+    and its control's readings at that q, the control's failing L.
+
+    Samples are grouped by replica seed, so each replica's weights are made
+    once. The weights and logits are those of ``payload``, the
+    configuration's payload module."""
+    gaps, ctl = [], []
     with jax.default_matmul_precision("highest"):
         for seed in sorted({s["seed"] for s in samples}):
             params = payload.init_params(seed, arch, dtype)
             for s in (x for x in samples if x["seed"] == seed):
                 seq = list(s["prompt"]) + list(s["tokens"][:-1])
                 ref = payload.forward(params, seq, arch)
-                widest = max(widest, float(token_gaps(
-                    ref, len(s["prompt"]), s["tokens"]).max()))
-                n_tok += len(s["tokens"])
+                gaps.append(token_gaps(ref, len(s["prompt"]), s["tokens"]))
                 if control:
                     low = payload.forward(params, seq, arch, fp8=True)
-                    widest_ctl = max(widest_ctl, float(control_gaps(
-                        ref, low, len(s["prompt"]), len(s["tokens"])).max()))
+                    ctl.append(control_gaps(ref, low, len(s["prompt"]),
+                                            len(s["tokens"])))
             del params
-    out = {"logit_gap": widest, "tokens_checked": n_tok}
+    pooled = np.concatenate(gaps)
+    out = {"logit_gap": at(pooled, quantile), "tokens_checked": len(pooled),
+           "program": spread(pooled)}
     if control:
-        out["control_logit_gap"] = widest_ctl
+        pooled = np.concatenate(ctl)
+        out["control_logit_gap"] = at(pooled, quantile)
+        out["control"] = spread(pooled)
     return out

@@ -18,14 +18,15 @@ CONFIG = "bench/configs/llm-smollm-360m.json"
 
 @pytest.fixture
 def wide(tree):
-    """The tiny tree with the configuration's own widths and limit."""
+    """The tiny tree with the configuration's own widths and reference
+    block (its limit, and its quantile where it names one)."""
     src = json.loads((REPO / CONFIG).read_text())
     widths = load_payload(tree, src).WIDTHS
 
     def widen(c):
         c.update({k: src[k] for k in widths})
         # the closed loop's first requests, all that a CPU serves in time
-        c["reference"] = {"tokens": 16, "limit": src["reference"]["limit"]}
+        c["reference"] = dict(src["reference"], tokens=16)
         return c
     _edit(tree / CONFIG, widen)
     return tree

@@ -342,7 +342,7 @@ def test_traced_rehearsal_reports_the_program_metrics(traced):
     listed = {m["name"]: m for m in spec["per_layer"]}
     for name, unit in NEW.items():
         assert listed[name]["unit"] == unit
-        assert listed[name]["workloads"] == ["llm.saturate"]
+        assert "llm.saturate" in listed[name]["workloads"]
         assert out["metrics"][name]["unit"] == unit
         assert out["metrics"][name]["value"] is not None
     assert 0 < out["metrics"]["slot_occupancy.saturate"]["value"] <= 100

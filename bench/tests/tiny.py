@@ -27,7 +27,9 @@ def _edit(path: Path, fn) -> None:
 
 def tiny_config(root: Path, path: Path) -> None:
     """Rewrite the configuration file at ``path`` in the tree at ``root`` to
-    its payload's ``TINY`` sizes and ``TINY_LIMIT``, and a small cluster."""
+    its payload's ``TINY`` sizes and ``TINY_LIMIT``, and a small cluster.
+    Of the ``reference`` block only ``tokens`` and ``limit`` change: its
+    ``quantile`` stays the configuration's."""
     from bench.harness import load_payload
 
     def tiny(c):
@@ -35,7 +37,7 @@ def tiny_config(root: Path, path: Path) -> None:
         c.update(payload.TINY)
         c["cluster"].update(slots=4, max_seq=64)
         c["drain_cap_s"] = 20
-        c["reference"] = {"tokens": 32, "limit": payload.TINY_LIMIT}
+        c["reference"].update(tokens=32, limit=payload.TINY_LIMIT)
         return c
     _edit(path, tiny)
 
