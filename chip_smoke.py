@@ -160,8 +160,7 @@ def check_reference(replica, prompts, max_slots: int) -> dict:
         logits, cache = replica._decode(
             replica.params, cache,
             {"tokens": jnp.asarray(toks), "cache_len": jnp.asarray(lens)})
-        # wait for every step, as the batcher does: each step returns a new
-        # cache, and steps queued without a wait each hold one on the device
+        # wait for every step, as the batcher does
         logits = np.asarray(logits[:n], np.float32)
         for r, p in enumerate(prompts):
             if t == len(p) - 1:

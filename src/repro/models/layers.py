@@ -229,8 +229,8 @@ def with_row(cache, row, pos) -> jax.Array:
     """``cache`` (..., B, Hkv, D, Smax), positions last, with ``row``
     (..., B, Hkv, D) at position ``pos`` (scalar or (B,); a position past the
     end writes nothing). A select: inside the layer scan XLA fuses it into
-    the attention's reads of the cache, so nothing is written there; over
-    the whole cache it is the one pass that makes the new cache."""
+    the attention's reads of the cache, so nothing is written there. Over
+    a whole cache it reads and writes every element."""
     hot = jnp.arange(cache.shape[-1]) == jnp.reshape(pos, (-1, 1))
     return jnp.where(hot[:, None, None, :], row[..., None], cache)
 

@@ -13,9 +13,12 @@ Design notes:
   * the decode KV cache is ``[L, B, Hkv, D, Smax]``, positions last: the
     TPU's own layout for it, which the attention's dots read with no
     relayout. The layer scan reads each layer's cache in place, with the new
-    row selected in, and returns only the rows; one select after the scan
-    writes them all. The input cache is not donated, so that select is also
-    the one pass that copies it into the output.
+    row selected in, and returns only the rows; after the scan, on one
+    TPU, a Pallas kernel writes each slot's rows in place into the cache,
+    which the serving executable takes donated (serving/exec_cache.py).
+    Undonated, XLA copies the cache before that write. On a mesh of more
+    than one device, and off the TPU, one select over the whole cache
+    writes them.
   * MoE: expert-parallel sorted dispatch under shard_map (see moe.py).
 """
 from __future__ import annotations
@@ -31,15 +34,29 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ArchConfig, ShapeSpec
+from repro.kernels.kv_write import WINDOW, write_rows_pallas
 from repro.models import layers as L
 from repro.models.api import RunConfig
-from repro.models.sharding import constrain
+from repro.models.sharding import constrain, mesh_axis_sizes
 from repro.models.moe import moe_ffn, moe_param_specs, moe_param_pspecs, \
     init_moe_params
 
 
 def _dt(cfg: ArchConfig):
     return jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32
+
+
+def _write_rows(cache, rows, pos):
+    """``cache`` (L, B, Hkv, D, Smax) with each slot's new rows (L, B, Hkv,
+    D) at its position ``pos``. On one TPU a Pallas kernel writes them in
+    place (kernels/kv_write.py). Off the TPU, on a mesh of more than one
+    device (XLA cannot partition a Pallas call) and for a length that is
+    no multiple of 128, a select over the whole cache writes them."""
+    if cache.shape[-1] % WINDOW or any(
+            n > 1 for n in mesh_axis_sizes().values()):
+        return L.with_row(cache, rows, pos)
+    return lax.platform_dependent(cache, rows, pos, tpu=write_rows_pallas,
+                                  default=L.with_row)
 
 
 class DecoderLM:
@@ -209,8 +226,9 @@ class DecoderLM:
         b = batch or shape.global_batch
         hkv, hd = cfg.n_kv_heads, cfg.resolved_head_dim
         dt = _dt(cfg)
-        z = jnp.zeros((cfg.n_layers, b, hkv, hd, shape.seq_len), dt)
-        return {"k": z, "v": z}
+        dims = (cfg.n_layers, b, hkv, hd, shape.seq_len)
+        # two buffers: a call that donates the cache cannot take one twice
+        return {"k": jnp.zeros(dims, dt), "v": jnp.zeros(dims, dt)}
 
     # ------------------------------------------------------------------ blocks
     def _positions(self, tokens, offset=0):
@@ -310,12 +328,13 @@ class DecoderLM:
         """Run the layer stack; returns (x, new_cache or None).
 
         In decode each layer reads its cache in place and gives back only its
-        new (k, v) rows; one select over the whole cache writes them after
-        the scan. Passed through the scan's ``xs`` and ``ys``, the cache
-        would be copied out and back a layer at a time on every step;
-        written into the scan's carry layer by layer, XLA relays the carried
-        cache positions-major for the write, and each layer back for the
-        dots."""
+        new (k, v) rows; after the scan ``_write_rows`` writes each slot's
+        rows at its length, in place on one TPU, where a select reads and
+        writes the whole cache. Passed through the scan's ``xs`` and
+        ``ys``, the cache would be copied out and back a layer at a time on
+        every step; written into the scan's carry layer by layer, XLA relays
+        the carried cache positions-major for the write, and each layer back
+        for the dots."""
         layers = params["layers"]
         if cache is None:
             block = self._block
@@ -349,8 +368,8 @@ class DecoderLM:
         if cache is None:
             return carry, None
         with jax.named_scope("kv_write"):
-            return carry[1], {"k": L.with_row(cache["k"], rows[0], cache_len),
-                              "v": L.with_row(cache["v"], rows[1], cache_len)}
+            return carry[1], {n: _write_rows(cache[n], r, cache_len)
+                              for n, r in zip(("k", "v"), rows)}
 
     # ------------------------------------------------------------------ steps
     def forward(self, params, batch) -> jax.Array:

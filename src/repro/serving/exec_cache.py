@@ -47,7 +47,7 @@ class CacheEntry:
     run_cfg: RunConfig
     mode: str
     model: object = None
-    decode: object = None          # jit(model.decode_step)
+    decode: object = None          # jit(model.decode_step), cache donated
     prefill: object = None         # jit(model.forward)
     # ShapeSpec -> compile wall seconds observed when the shape was warmed
     shapes: Dict[ShapeSpec, float] = field(default_factory=dict)
@@ -100,7 +100,8 @@ class ExecutableCache:
             self.misses += 1
             entry = CacheEntry(cfg=cfg, run_cfg=run_cfg, mode=mode)
             entry.model = build_model(cfg, run_cfg)
-            entry.decode = jax.jit(entry.model.decode_step)
+            # the cache is donated: the step writes its new rows in place
+            entry.decode = jax.jit(entry.model.decode_step, donate_argnums=1)
             entry.prefill = jax.jit(entry.model.forward)
             self._entries[key] = entry
             if self.capacity is not None:

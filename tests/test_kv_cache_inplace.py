@@ -1,10 +1,13 @@
-"""The decode step reads the KV cache in place and writes it in one pass.
+"""The decode step reads the KV cache in place and writes only its new rows.
 
 ``DecoderLM._stack`` once passed the cache through the layer scan's ``xs``
 and ``ys``: every layer's cache was copied out, written, and stacked back on
 every step. ``XsYsLM`` keeps that formulation as the reference. The current
 step must give the same logits and the same cache, bit for bit, and its
-layer scan must move no cache-sized array.
+layer scan must move no cache-sized array. The served executable donates
+the cache, and on a TPU a Pallas kernel writes each slot's rows into it;
+the step must agree with the undonated one, and the kernel with
+``with_row``.
 """
 import jax
 import jax.numpy as jnp
@@ -14,8 +17,11 @@ from jax import lax
 
 from repro.configs import get_config
 from repro.configs.base import ShapeSpec
+from repro.kernels.kv_write import write_rows_pallas
+from repro.models import layers as L
 from repro.models.api import RunConfig
 from repro.models.transformer import DecoderLM
+from repro.serving.exec_cache import ExecutableCache
 
 B, SMAX, STEPS = 4, 32, 6
 
@@ -131,3 +137,71 @@ def test_layer_scan_moves_no_cache():
     assert all(int(np.prod(v.aval.shape)) < layer_size for v in moved)
     rows = (cfg.n_layers, B, cfg.n_kv_heads, cfg.resolved_head_dim)
     assert [v.aval.shape for v in scan.outvars].count(rows) == 2
+
+
+# positions as a function of the cache's length: scalar, then one a slot
+POSITIONS = {
+    "first": lambda s: 0,
+    "last": lambda s: s - 1,
+    "past_end": lambda s: s,
+    "per_slot": lambda s: [0, s - 1, s, 7],
+    "per_slot_outside": lambda s: [-1, 3, s + 4, s - 1],
+    "per_slot_past_end": lambda s: [s] * B,
+    "per_slot_windows": lambda s: [127, 128, 255, 300],
+}
+
+
+@pytest.mark.parametrize("smax", [128, 384])
+@pytest.mark.parametrize("pos", list(POSITIONS.values()), ids=list(POSITIONS))
+def test_kv_write_kernel_matches_with_row(pos, smax):
+    """The Pallas kernel (interpreted) puts each slot's rows where
+    ``with_row`` selects them, bit for bit, across windows of 128 positions
+    and blocks of 8 layers; a position outside the cache writes nothing."""
+    keys = jax.random.split(jax.random.PRNGKey(2), 2)
+    cache = jax.random.normal(keys[0], (16, B, 2, 8, smax), jnp.bfloat16)
+    rows = jax.random.normal(keys[1], (16, B, 2, 8), jnp.bfloat16)
+    pos = jnp.asarray(pos(smax), jnp.int32)
+    got = np.asarray(write_rows_pallas(cache, rows, pos, interpret=True))
+    np.testing.assert_array_equal(got, np.asarray(L.with_row(cache, rows,
+                                                             pos)))
+    inside = (np.asarray(pos) >= 0) & (np.asarray(pos) < smax)
+    moved = ~np.all(got == np.asarray(cache), axis=(0, 2, 3, 4))
+    np.testing.assert_array_equal(moved, np.broadcast_to(inside, (B,)))
+
+
+@pytest.mark.parametrize("arch", ["smollm-360m", "qwen3-32b",
+                                  "kimi-k2-1t-a32b"])
+def test_init_cache_gives_k_and_v_their_own_buffers(arch):
+    """A call that donates the cache refuses one buffer handed in twice."""
+    model = DecoderLM(get_config(arch).reduced(), RunConfig())
+    cache = model.init_cache(ShapeSpec("t", SMAX, B, "decode"))
+    assert (cache["k"].unsafe_buffer_pointer()
+            != cache["v"].unsafe_buffer_pointer())
+
+
+@pytest.mark.parametrize("arch", ["smollm-360m", "qwen3-32b",
+                                  "kimi-k2-1t-a32b"])
+def test_donating_executable_matches_undonated(arch):
+    """Six steps of the batcher's signature through the served executable,
+    which donates its cache: each call deletes the cache it was given, and
+    the logits and the final cache equal the undonated step's."""
+    cfg = get_config(arch).reduced()
+    entry = ExecutableCache().get(cfg, RunConfig(q_chunk=8, kv_chunk=8))
+    model = entry.model
+    params = model.init_params(jax.random.PRNGKey(0))
+    shape = ShapeSpec("t", SMAX, B, "decode")
+    toks = jax.random.randint(jax.random.PRNGKey(3), (B, STEPS), 0,
+                              cfg.vocab)
+    lens = jnp.array([3, 0, 11, SMAX - STEPS], jnp.int32)
+    want_logits, want_cache = _steps(model, params, model.init_cache(shape),
+                                     lens, toks)
+    cache = model.init_cache(shape)
+    for t in range(STEPS):
+        given = cache
+        logits, cache = entry.decode(params, given, {
+            "tokens": toks[:, t:t + 1], "cache_len": lens + t})
+        assert all(a.is_deleted() for a in jax.tree.leaves(given))
+        np.testing.assert_array_equal(np.asarray(logits), want_logits[t])
+    for n in ("k", "v"):
+        np.testing.assert_array_equal(np.asarray(cache[n]), want_cache[n])
+
